@@ -56,8 +56,9 @@ def run_ichinose() -> list[tuple[int, float]]:
     return out
 
 
-def ocampo(n_users: int, horizon: float = 30.0) -> float:
-    """Returns mean measured SPE execution wall time (s)."""
+def ocampo_spec(n_users: int) -> PipelineSpec:
+    """Broker + one ``traffic_metrics`` SPE (1 s window, 0.2 s poll) +
+    ``n_users`` Poisson packet users at 20 pps of 256-byte packets."""
     spec = PipelineSpec()
     spec.add_switch("s1")
     spec.add_host("b").add_link("b", "s1", lat=0.5, bw=1000.0)
@@ -71,7 +72,13 @@ def ocampo(n_users: int, horizon: float = 30.0) -> float:
         spec.add_host(h).add_link(h, "s1", lat=0.5, bw=100.0)
         spec.add_producer(h, "PACKET", topic="pkts", ratePps=20.0,
                           pktBytes=256)
-    eng, mon, wall = run_spec(spec, until=horizon, seed=n_users)
+    return spec
+
+
+def ocampo(n_users: int, horizon: float = 30.0) -> float:
+    """Returns mean measured SPE execution wall time (s)."""
+    eng, mon, wall = run_spec(ocampo_spec(n_users), until=horizon,
+                              seed=n_users)
     walls = [e["wall"] for e in mon.events_of("spe_exec")]
     assert walls, "SPE executed no windows"
     return float(np.mean(walls[2:])) if len(walls) > 4 else float(

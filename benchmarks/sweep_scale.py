@@ -136,8 +136,8 @@ def metrics_fingerprint(m: dict, exclude: frozenset = _NONDET_KEYS
     return hashlib.sha256(blob.encode()).hexdigest()
 
 
-def _run_sized(n_hosts: int, horizon: float, profile: bool = False,
-               extra: dict | None = None) -> dict:
+def run_sized(n_hosts: int, horizon: float, profile: bool = False,
+              extra: dict | None = None) -> dict:
     """One instrumented scale point: per-phase wall-clock breakdown."""
     params = {**scale_base(horizon), "n_hosts": n_hosts, **(extra or {})}
     if profile:
@@ -179,8 +179,8 @@ def _compare_route_modes(n_hosts: int, horizon: float) -> dict:
     asserted, deterministic solver-reduction gated."""
     runs = {}
     for mode in ("ondemand", "table"):
-        m = _run_sized(n_hosts, horizon,
-                       extra={"route_mode": mode, "chaos": 2})
+        m = run_sized(n_hosts, horizon,
+                      extra={"route_mode": mode, "chaos": 2})
         m.pop("phases")
         runs[mode] = m
     before, after = runs["ondemand"], runs["table"]
@@ -221,9 +221,9 @@ def _compare_fetch_modes(n_hosts: int, horizon: float) -> dict:
     deterministic event-count reduction gated (PR 9)."""
     runs = {}
     for mode in ("legacy", "fused"):
-        m = _run_sized(n_hosts, horizon,
-                       extra={"fetch_mode": mode, "chaos": 2,
-                              "partitions": 4})
+        m = run_sized(n_hosts, horizon,
+                      extra={"fetch_mode": mode, "chaos": 2,
+                             "partitions": 4})
         m.pop("phases")
         runs[mode] = m
     before, after = runs["legacy"], runs["fused"]
@@ -261,7 +261,7 @@ def run(*, smoke: bool = False, full: bool = False, profile: bool = False,
     results: dict = {"sizes": {}}
 
     for n in sizes:
-        m = _run_sized(n, horizon)
+        m = run_sized(n, horizon)
         results["sizes"][n] = {
             "engine_events": m["engine_events"],
             "wall_s": m["wall_s"],
@@ -283,7 +283,7 @@ def run(*, smoke: bool = False, full: bool = False, profile: bool = False,
              f"sim_rate={m['sim_s'] / m['wall_s']:.1f}x")
         if profile:
             # separate instrumented pass: never reuse its wall clock
-            p = _run_sized(n, horizon, profile=True)
+            p = run_sized(n, horizon, profile=True)
             results["sizes"][n]["profile"] = p["profile"]
             emit(f"sweep_scale/{n}nodes_profile",
                  p["profile"]["wall_s"].get("netem_path", 0.0) * 1e6,

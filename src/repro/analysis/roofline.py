@@ -20,7 +20,8 @@ instead:
   ``replica_groups=[G,N]<=[dims]T(perm)``.
 
 All shapes in post-SPMD HLO are per-device shards, so every total here is
-*per chip*; the roofline terms divide by per-chip peaks directly.
+*per chip*; the roofline terms divide by the per-chip peaks of the device
+they model, :data:`MODELED_DEVICE` (peaks from :mod:`repro.analysis.peaks`).
 """
 from __future__ import annotations
 
@@ -33,11 +34,9 @@ from typing import Optional
 
 import numpy as np
 
-from repro.core.netem import (
-    DCN_BW, HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-)
+from repro.analysis.peaks import peaks_for
 
-CHIPS_PER_HOST = 4          # v5e: DCN bandwidth is per host
+MODELED_DEVICE = "TPU v5 lite"      # jax device_kind of a TPU v5e chip
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -522,6 +521,7 @@ def analyze_hlo(text: str, *, chips_per_pod: int = 256,
 
 @dataclass
 class RooflineReport:
+    device_kind: str
     t_compute: float
     t_memory: float
     t_ici: float
@@ -543,17 +543,19 @@ class RooflineReport:
 
 def roofline_terms(analysis: HLOAnalysis, *, model_flops_total: float,
                    n_chips: int) -> RooflineReport:
-    t_c = analysis.flops / PEAK_FLOPS_BF16
-    t_m = analysis.hbm_bytes / HBM_BW
-    t_i = analysis.ici_bytes / ICI_BW
-    t_d = analysis.dcn_bytes / (DCN_BW / CHIPS_PER_HOST)
+    """Roofline terms on :data:`MODELED_DEVICE`."""
+    pk = peaks_for(MODELED_DEVICE)
+    t_c = analysis.flops / pk.bf16_flops
+    t_m = analysis.hbm_bytes / pk.hbm_bytes_per_s
+    t_i = analysis.ici_bytes / pk.ici_bytes_per_s
+    t_d = analysis.dcn_bytes / (pk.dcn_bytes_per_s / pk.chips_per_host)
     terms = {"compute": t_c, "memory": t_m, "ici": t_i, "dcn": t_d}
     bottleneck = max(terms, key=terms.get)
     model_per_dev = model_flops_total / n_chips
     useful = model_per_dev / analysis.flops if analysis.flops else 0.0
     # fraction of roofline: time the compute-bound ideal would take over
     # the actual bound term (1.0 = perfectly compute-bound at peak)
-    ideal = model_per_dev / PEAK_FLOPS_BF16
+    ideal = model_per_dev / pk.bf16_flops
     frac = ideal / max(max(terms.values()), 1e-30)
-    return RooflineReport(t_c, t_m, t_i, t_d, bottleneck, model_per_dev,
-                          useful, frac)
+    return RooflineReport(MODELED_DEVICE, t_c, t_m, t_i, t_d, bottleneck,
+                          model_per_dev, useful, frac)

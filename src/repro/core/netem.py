@@ -39,10 +39,6 @@ invalidates only the loss-keep rows — gray-loss faults must go through
 that seam, never mutate ``LinkCfg.loss_pct`` mid-run directly.
 ``set_host_slow`` bumps nothing by design: slow extras apply at query
 time on top of the table lookup.
-
-The same module exports the TPU interconnect constants used by the roofline
-analysis (DESIGN.md §7) so that "the network model" has a single home for
-both the pipeline gym and the SPMD collective analysis.
 """
 from __future__ import annotations
 
@@ -58,17 +54,6 @@ from scipy.sparse import csr_matrix
 from scipy.sparse.csgraph import dijkstra as _apsp_dijkstra
 
 from repro.kernels import netcalc
-
-# ---------------------------------------------------------------------------
-# TPU v5e interconnect / chip constants (roofline; DESIGN.md §7)
-# ---------------------------------------------------------------------------
-
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link (intra-pod, per direction)
-DCN_BW = 25e9                 # bytes/s per host across pods ("pod" axis)
-ICI_LAT = 1e-6                # seconds, per hop
-DCN_LAT = 10e-6               # seconds
 
 
 @dataclass
@@ -767,17 +752,6 @@ class Network:
         if self.reach_cache:
             self._lat_memo[(src, dst)] = (val,)
         return val
-
-
-# ---------------------------------------------------------------------------
-# Roofline helpers (per-chip interconnect model for the SPMD program)
-# ---------------------------------------------------------------------------
-
-
-def collective_time_s(ici_bytes_per_chip: float,
-                      dcn_bytes_per_chip: float) -> float:
-    """Lower-bound time to move the per-chip collective traffic."""
-    return ici_bytes_per_chip / ICI_BW + dcn_bytes_per_chip / DCN_BW
 
 
 def one_big_switch(hosts: list[str], *, lat_ms: float = 0.1,

@@ -42,6 +42,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
+from repro import chip
 from repro.kernels import cohort as cohort_kernels
 
 
@@ -498,9 +499,11 @@ class WindowAggregate(Operator):
             return 0.0
 
     def _reduce_fn(self, n: int) -> Callable:
-        import jax
-        import jax.numpy as jnp
         if n not in self._jit_cache:
+            chip.init_jax()           # refuses in sweep pool workers
+            import jax
+            import jax.numpy as jnp
+
             @jax.jit
             def f(vals, mask):
                 kept = jnp.where(mask, vals, 0.0)

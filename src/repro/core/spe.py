@@ -81,6 +81,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
+from repro import chip
 from repro.core import state as state_mod
 from repro.core.broker import BatchView
 from repro.core.operators import (
@@ -636,6 +637,7 @@ _LEXICON = {
 
 
 def _sentiment_fn(payload):
+    chip.init_jax()               # refuses in sweep pool workers
     import jax.numpy as jnp
     d = _data_of(payload)
     text = d["text"] if isinstance(d, dict) else str(d)
@@ -663,6 +665,7 @@ class RideSelectQuery(Query):
     """Best tipping areas: groupby(area) of mean tip over the window."""
 
     def _run(self, spe, eng, records):
+        chip.init_jax()               # refuses in sweep pool workers
         import jax
         import jax.numpy as jnp
         rides = [self._data(r) for r in records]
@@ -722,6 +725,7 @@ class MaritimeQuery(Query):
 class FraudSVMQuery(Query):
     def __init__(self, comp):
         super().__init__(comp)
+        chip.init_jax()               # refuses in sweep pool workers
         import jax
         import jax.numpy as jnp
         dim = int(comp.get("dim", 8))
@@ -783,6 +787,7 @@ class TrafficMetricsQuery(Query):
         self._jit_cache: dict[int, Callable] = {}
 
     def _metrics_fn(self, n: int):
+        chip.init_jax()               # refuses in sweep pool workers
         import jax
         import jax.numpy as jnp
         if n not in self._jit_cache:
@@ -838,6 +843,7 @@ class LMGenerateQuery(Query):
         self._built = False
 
     def _build(self):
+        chip.init_jax()               # refuses in sweep pool workers
         import jax
         import jax.numpy as jnp
         from repro.configs import get_config, reduce_for_smoke
@@ -937,6 +943,7 @@ class LMTrainQuery(Query):
         self._built = False
 
     def _build(self):
+        chip.init_jax()               # refuses in sweep pool workers
         from repro.configs import get_config, reduce_for_smoke
 
         arch = self.comp.get("arch", "xlstm-125m")

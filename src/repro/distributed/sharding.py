@@ -18,32 +18,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names=None,
-                     check_vma=False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes top-level ``jax.shard_map(..., axis_names=...,
-    check_vma=...)``; 0.4.x only has ``jax.experimental.shard_map`` with
-    the (``auto``, ``check_rep``) spelling.  ``axis_names`` here is the
-    set of *manual* axes (new-API convention); on the old API the
-    complement of the mesh axes is passed as ``auto``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(f, **kw)
-
-
 # Default logical-axis -> mesh-axes candidates.  Order within the tuple is
 # the sharding order; resolution drops axes that don't divide or collide.
 def logical_rules(cfg, mesh: Mesh) -> dict[str, tuple[str, ...]]:

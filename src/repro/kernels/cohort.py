@@ -48,6 +48,8 @@ def _pane_starts_np(event_times, size_s: float) -> np.ndarray:
 
 
 def _pane_starts_jax(event_times, size_s: float) -> np.ndarray:
+    from repro import chip
+    chip.init_jax()               # refuses in sweep pool workers
     import jax
     if not jax.config.jax_enable_x64:
         raise RuntimeError(

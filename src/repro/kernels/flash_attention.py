@@ -72,23 +72,38 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 def pick_block(s: int, want: int) -> int:
-    b = min(want, s)
-    while s % b:
+    """Block length along a sequence axis of length ``s``.
+
+    Mosaic tiles the second-to-last block dim in rows of 8, so a block
+    is either the whole axis or a multiple of 8 that divides it; any
+    other length raises here instead of in the TPU compiler.
+    """
+    if s <= want:
+        return s
+    b = want
+    while b >= 8 and s % b:
         b //= 2
-    return max(b, 1)
+    if b < 8 or b % 8:
+        raise ValueError(
+            f"no block of at most {want} rows tiles a sequence of {s}: "
+            "a block must be the whole axis or a multiple of 8 dividing "
+            "it; pad the sequence to a multiple of 8")
+    return b
 
 
 def flash_attention_fwd(q, k, v, *, scale: float, causal: bool = True,
                         window: int = 0, softcap: float = 0.0,
                         block_q: int = 256, block_k: int = 256,
-                        interpret: bool | None = None):
-    """q: (B, Sq, NH, hd); k, v: (B, Sk, KV, hd) -> (B, Sq, NH, hd)."""
+                        interpret: bool = False):
+    """q: (B, Sq, NH, hd); k, v: (B, Sk, KV, hd) -> (B, Sq, NH, hd).
+
+    Compiled for the TPU unless ``interpret=True`` (tests on the CPU);
+    lowering for any other platform raises.
+    """
     B, Sq, NH, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     assert NH % KV == 0, (NH, KV)
     G = NH // KV
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
 
     bq = pick_block(Sq, block_q)
     bk = pick_block(Sk, block_k)
@@ -117,9 +132,9 @@ def flash_attention_fwd(q, k, v, *, scale: float, causal: bool = True,
             pltpu.VMEM((bq, 1), jnp.float32),     # m (running max)
             pltpu.VMEM((bq, 1), jnp.float32),     # l (running denom)
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ) if not interpret else None,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        backend="mosaic_tpu",        # any other platform refuses to lower
     )(qh, kh, vh)
     return out.reshape(B, NH, Sq, hd).transpose(0, 2, 1, 3)

@@ -67,14 +67,16 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 def flash_decode(q, k_cache, v_cache, pos, *, scale: float,
                  window: int = 0, softcap: float = 0.0,
-                 block_k: int = 512, interpret: bool | None = None):
-    """q: (B, NH, hd); caches: (B, S, KV, hd); pos: scalar -> (B, NH, hd)."""
+                 block_k: int = 512, interpret: bool = False):
+    """q: (B, NH, hd); caches: (B, S, KV, hd); pos: scalar -> (B, NH, hd).
+
+    Compiled for the TPU unless ``interpret=True`` (tests on the CPU);
+    lowering for any other platform raises.
+    """
     B, NH, hd = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
     assert NH % KV == 0
     G = NH // KV
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
 
     bk = pick_block(S, block_k)
     k_blocks = S // bk
@@ -104,9 +106,9 @@ def flash_decode(q, k_cache, v_cache, pos, *, scale: float,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ) if not interpret else None,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        backend="mosaic_tpu",        # any other platform refuses to lower
     )(pos_arr, qh, kh, vh)
     return out.reshape(B, NH, hd)

@@ -51,6 +51,8 @@ def _delay_many_np(lat_s: np.ndarray, bneck_Bps: np.ndarray, nbytes: int,
 
 
 def _delay_many_jax(lat_s, bneck_Bps, nbytes, extra_s):
+    from repro import chip
+    chip.init_jax()               # refuses in sweep pool workers
     import jax
     if not jax.config.jax_enable_x64:
         raise RuntimeError(

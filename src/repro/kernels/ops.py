@@ -18,20 +18,23 @@ from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.flash_decode import flash_decode as _flash_decode_impl
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, scale, causal=True, window=0, softcap=0.0):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def flash_attention(q, k, v, scale, causal=True, window=0, softcap=0.0,
+                    interpret=False):
     """q: (B, S, NH, hd); k, v: (B, S, KV, hd) -> (B, S, NH, hd)."""
     return flash_attention_fwd(q, k, v, scale=scale, causal=causal,
-                               window=window, softcap=softcap)
+                               window=window, softcap=softcap,
+                               interpret=interpret)
 
 
-def _fa_fwd(q, k, v, scale, causal, window, softcap):
+def _fa_fwd(q, k, v, scale, causal, window, softcap, interpret):
     out = flash_attention_fwd(q, k, v, scale=scale, causal=causal,
-                              window=window, softcap=softcap)
+                              window=window, softcap=softcap,
+                              interpret=interpret)
     return out, (q, k, v)
 
 
-def _fa_bwd(scale, causal, window, softcap, res, g):
+def _fa_bwd(scale, causal, window, softcap, interpret, res, g):
     q, k, v = res
     _, vjp = jax.vjp(
         lambda q, k, v: ref.attention(q, k, v, scale=scale, causal=causal,
@@ -43,7 +46,9 @@ def _fa_bwd(scale, causal, window, softcap, res, g):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-def flash_decode(q, k_cache, v_cache, pos, *, scale, window=0, softcap=0.0):
+def flash_decode(q, k_cache, v_cache, pos, *, scale, window=0, softcap=0.0,
+                 interpret=False):
     """q: (B, NH, hd); caches: (B, S, KV, hd); pos scalar -> (B, NH, hd)."""
     return _flash_decode_impl(q, k_cache, v_cache, pos, scale=scale,
-                              window=window, softcap=softcap)
+                              window=window, softcap=softcap,
+                              interpret=interpret)

@@ -1,11 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST run before any jax import (jax locks the device
-count on first init); they are intentionally placed before the module
-docstring's siblings and every other import.
+:func:`main` forces 512 host devices through ``XLA_FLAGS`` before it
+imports JAX (JAX fixes the device count when it first initialises), so
+importing this module changes nothing in the caller's process.
 
 For each cell the dry-run:
   1. builds the production mesh (16×16 single-pod, or 2×16×16 multi-pod),
@@ -22,15 +19,12 @@ Run everything: python -m repro.launch.dryrun --all [--multi-pod]
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 
-import jax
-
 from repro.analysis.roofline import analyze_hlo, roofline_terms
 from repro.configs import SHAPES, get_config, list_configs
-from repro.launch.mesh import make_production_mesh, mesh_axis_sizes
-from repro.train import make_step_bundle
 
 DEFAULT_OUT = "results/dryrun"
 
@@ -57,6 +51,10 @@ def _memory_analysis_dict(compiled) -> dict:
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
              out_dir: str = DEFAULT_OUT, save_hlo: bool = False,
              overrides: dict | None = None, tag: str = "") -> dict:
+    import jax
+    from repro.launch.mesh import make_production_mesh
+    from repro.train import make_step_bundle
+
     cfg = get_config(arch)
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
@@ -156,6 +154,9 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    from repro import chip
+    chip.init_jax()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default=None)
     p.add_argument("--shape", default=None, choices=list(SHAPES) + [None])

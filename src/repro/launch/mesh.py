@@ -8,18 +8,21 @@ import and then calls it.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (elastic rescale path uses shrunk shapes)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (elastic rescale path uses shrunk shapes).
 
+    Axes are ``Auto``: the step builders place activations with
+    ``with_sharding_constraint``, which refuses ``Explicit`` axes (the
+    ``jax.make_mesh`` default in JAX 0.9).
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
-def mesh_axis_sizes(mesh) -> dict[str, int]:
-    return dict(zip(mesh.axis_names, mesh.devices.shape))

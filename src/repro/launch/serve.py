@@ -16,6 +16,7 @@ import argparse
 
 import numpy as np
 
+from repro import chip
 from repro.core import Engine, PipelineSpec
 
 
@@ -40,7 +41,7 @@ def build_spec(args) -> tuple[PipelineSpec, object]:
     return spec, sink
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="xlstm-125m")
     p.add_argument("--requests", type=int, default=12)
@@ -52,14 +53,22 @@ def main() -> None:
     p.add_argument("--bw", type=float, default=1000.0)
     p.add_argument("--mode", default="kraft", choices=["zk", "kraft"])
     p.add_argument("--seed", type=int, default=0)
-    args = p.parse_args()
+    return p.parse_args(argv)
 
+
+def run(args):
+    """Serve ``args.requests`` requests; returns (monitor, sink runtime)."""
     spec, sink = build_spec(args)
     eng = Engine(spec, seed=args.seed)
-    horizon = args.requests * args.interval + 30.0
-    mon = eng.run(until=horizon)
-
+    mon = eng.run(until=args.requests * args.interval + 30.0)
     sink_rt = [rt for rt in eng.runtimes if rt.name == sink.name][0]
+    return mon, sink_rt
+
+
+def main() -> None:
+    args = parse_args()
+    chip.init_jax()
+    mon, sink_rt = run(args)
     lat = mon.e2e_latency()
     print(f"[serve] {args.arch}: {sink_rt.n_received}/{args.requests} "
           f"responses")

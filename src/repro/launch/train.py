@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import SHAPES, get_config, reduce_for_smoke
+from repro import chip
+from repro.configs import get_config, reduce_for_smoke
 from repro.configs.base import ShapeCfg
 from repro.data import make_train_batches
 from repro.data.pipeline import make_source, Prefetcher
@@ -64,6 +65,7 @@ def main() -> None:
     p.add_argument("--gym", action="store_true")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
+    chip.init_jax()
 
     if args.gym:
         run_gym(args)

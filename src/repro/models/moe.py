@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import act_constrain, shard_map_compat
+from repro.distributed.sharding import act_constrain
 from repro.models.params import pmeta, dense_init
 from repro.models.layers import _act
 
@@ -254,7 +254,7 @@ def _moe_apply_ep(params, x, cfg, mesh):
                 (P("model", None, None) if w_gate is not None else P()),
                 P("model", None, None))
     out_specs = (P(dp_spec, None, None), P(), P())
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False)
     out, aux, drop = sharded(

@@ -36,6 +36,12 @@ way (workers unpickle them by reference), and scripts that call
 ``workers <= 1`` runs inline in this process (no pickling constraints —
 handy for tests and debugging).
 
+One process per chip: pool workers are marked with
+:func:`repro.chip.forbid_jax`, so a scenario whose spec holds a JAX
+operator stage fails in the worker before JAX is imported there.  The
+worker hands it back, and it reruns inline in this process — the one
+that owns the chip — after the pool has drained.
+
 Repeats contract: ``repeats > 1`` keeps the best wall time and
 **asserts** every deterministic metric is identical across the repeats
 — a cheap standing guard for the cross-process determinism contract
@@ -50,6 +56,7 @@ import os
 import sys
 from typing import Callable, Optional
 
+from repro import chip
 from repro.core.engine import Engine
 from repro.sweep.grid import Scenario, SweepSpec, scenario_id
 from repro.sweep.results import TIMING_KEYS, SweepResults
@@ -97,6 +104,33 @@ def _run_one(task: _Task) -> dict:
             json.dump(row, f, default=repr)
         os.replace(tmp, cache_path)
     return row
+
+
+def _init_worker() -> None:
+    chip.forbid_jax("sweep pool worker")
+
+
+def _run_in_worker(task: _Task) -> dict:
+    """Pool side of :func:`_run_one`: a scenario with a JAX stage is
+    handed back to the owning process instead of run here."""
+    try:
+        return _run_one(task)
+    except chip.JaxForbidden:
+        return {"scenario_id": task[0], "owner_only": True}
+
+
+def _drain(results, pending: list[_Task], rows: dict,
+           progress: Optional[Callable[[str], None]]) -> list[_Task]:
+    """Collect pool rows; returns the tasks the owning process must run."""
+    owner_only = set()
+    for row in results:
+        if row.get("owner_only"):
+            owner_only.add(row["scenario_id"])
+            continue
+        rows[row["scenario_id"]] = row
+        if progress:
+            progress(f"  ran {row['scenario_id']}")
+    return [t for t in pending if t[0] in owner_only]
 
 
 def _load_cached(path: str, scenario: Scenario) -> Optional[dict]:
@@ -166,7 +200,7 @@ def warm_pool(workers: int, mp_context: Optional[str] = None):
         # the fork server template; JAX stays un-imported there, so
         # forked workers start warm *and* JAX-clean
         ctx.set_forkserver_preload(_PRELOAD)
-    pool = ctx.Pool(workers)
+    pool = ctx.Pool(workers, initializer=_init_worker)
     _warm_pool = (pool, workers, method)
     return pool
 
@@ -234,18 +268,12 @@ def run_sweep(sweep: SweepSpec, *, workers: int = 2,
                  f"({len(rows)} cached, {len(pending)} to run, "
                  f"workers={workers})")
     if pending:
-        if workers <= 1 or len(pending) == 1:
-            for t in pending:
-                rows[t[0]] = _run_one(t)
-                if progress:
-                    progress(f"  ran {t[0]}")
-        elif warm:
+        owner = pending                 # tasks run inline, in this process
+        if workers > 1 and len(pending) > 1 and warm:
             pool = warm_pool(workers, mp_context)
             try:
-                for row in pool.imap_unordered(_run_one, pending):
-                    rows[row["scenario_id"]] = row
-                    if progress:
-                        progress(f"  ran {row['scenario_id']}")
+                owner = _drain(pool.imap_unordered(_run_in_worker, pending),
+                               pending, rows, progress)
             except BaseException:
                 # Ctrl-C / a failing scenario: abandoned tasks would
                 # keep running invisibly on the persistent workers and
@@ -254,11 +282,14 @@ def run_sweep(sweep: SweepSpec, *, workers: int = 2,
                 # already cache-written by workers survive and resume)
                 shutdown_pool()
                 raise
-        else:
+        elif workers > 1 and len(pending) > 1:
             ctx = mp.get_context(_pick_method(mp_context))
-            with ctx.Pool(min(workers, len(pending))) as pool:
-                for row in pool.imap_unordered(_run_one, pending):
-                    rows[row["scenario_id"]] = row
-                    if progress:
-                        progress(f"  ran {row['scenario_id']}")
+            with ctx.Pool(min(workers, len(pending)),
+                          initializer=_init_worker) as pool:
+                owner = _drain(pool.imap_unordered(_run_in_worker, pending),
+                               pending, rows, progress)
+        for t in owner:
+            rows[t[0]] = _run_one(t)
+            if progress:
+                progress(f"  ran {t[0]}")
     return SweepResults([rows[s.id] for s in scens], name=sweep.name)

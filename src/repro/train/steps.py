@@ -30,7 +30,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ArchConfig, ShapeCfg
 from repro.distributed.sharding import (
     activation_sharding, batch_spec, logical_rules, resolve_axes_tree,
-    shard_map_compat,
 )
 from repro.models import Model
 from repro.optim import AdamW, OptConfig, cosine_warmup
@@ -235,7 +234,7 @@ def _train_bundle(cfg, shape, mesh, model, donate) -> StepBundle:
                     loss = jax.lax.pmean(loss, "pod")
                     return grads, new_ef, loss
 
-                sharded = shard_map_compat(
+                sharded = jax.shard_map(
                     per_pod, mesh=mesh, axis_names={"pod"},
                     in_specs=(P(), P(), P("pod")), out_specs=(P(), P(), P()),
                     check_vma=False)

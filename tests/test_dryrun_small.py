@@ -23,10 +23,11 @@ SCRIPT = textwrap.dedent("""
     from repro.configs.base import ShapeCfg
     from repro.train import make_step_bundle
     from repro.analysis.roofline import analyze_hlo, roofline_terms
+    from repro.launch.mesh import make_mesh
 
     cfg = reduce_for_smoke(get_config("qwen2-7b"))
     shape = ShapeCfg("t", 64, 8, "train")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with mesh:
         b = make_step_bundle(cfg, shape, mesh)
         jitted = jax.jit(b.step_fn, in_shardings=b.in_shardings,

@@ -1,4 +1,8 @@
-"""Pallas kernel sweeps vs the pure-jnp oracle (interpret mode on CPU)."""
+"""Pallas kernel sweeps vs the pure-jnp oracle.
+
+The kernels compile only for the TPU; on the CPU these tests ask for
+interpret mode explicitly.
+"""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,7 +35,7 @@ def test_flash_attention_vs_ref(case, dtype):
     q, k, v = t((B, S, NH, hd), dtype), t((B, S, KV, hd), dtype), \
         t((B, S, KV, hd), dtype)
     scale = hd ** -0.5
-    out = ops.flash_attention(q, k, v, scale, True, window, cap)
+    out = ops.flash_attention(q, k, v, scale, True, window, cap, True)
     want = ref.attention(q, k, v, scale=scale, causal=True, window=window,
                          softcap=cap)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
@@ -57,7 +61,7 @@ def test_flash_decode_vs_ref(case, dtype):
     kc, vc = t((B, S, KV, hd), dtype), t((B, S, KV, hd), dtype)
     scale = hd ** -0.5
     out = ops.flash_decode(q, kc, vc, pos, scale=scale, window=window,
-                           softcap=cap)
+                           softcap=cap, interpret=True)
     want = ref.decode(q, kc, vc, pos, scale=scale, window=window,
                       softcap=cap)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
@@ -73,7 +77,8 @@ def test_flash_attention_grads_match_ref():
     s = 32 ** -0.5
 
     def f(q, k, v):
-        return jnp.sum(ops.flash_attention(q, k, v, s, True, 0, 0.0) ** 2)
+        return jnp.sum(
+            ops.flash_attention(q, k, v, s, True, 0, 0.0, True) ** 2)
 
     def fr(q, k, v):
         return jnp.sum(ref.attention(q, k, v, scale=s) ** 2)
@@ -91,12 +96,22 @@ def test_flash_blocks_do_not_change_result():
     q, k, v = t((1, 128, 4, 32), jnp.float32), \
         t((1, 128, 2, 32), jnp.float32), t((1, 128, 2, 32), jnp.float32)
     outs = [
-        flash_attention_fwd(q, k, v, scale=0.1, block_q=bq, block_k=bk)
+        flash_attention_fwd(q, k, v, scale=0.1, block_q=bq, block_k=bk,
+                            interpret=True)
         for bq, bk in [(32, 32), (64, 128), (128, 64), (16, 16)]
     ]
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
                                    atol=1e-5, rtol=1e-5)
+
+
+def test_kernels_refuse_non_tpu_backend_without_interpret():
+    """No silent interpret fallback: off the TPU, lowering raises."""
+    q, k = t((1, 64, 4, 32), jnp.float32), t((1, 64, 2, 32), jnp.float32)
+    with pytest.raises(ValueError, match="interpret"):
+        ops.flash_attention(q, k, k, 0.1, True, 0, 0.0)
+    with pytest.raises(ValueError, match="interpret"):
+        ops.flash_decode(q[:, 0], k, k, 3, scale=0.1)
 
 
 def test_chunked_xla_attention_matches_ref():

@@ -100,10 +100,9 @@ def test_compressed_pod_allreduce_shard_map():
                                               "pod")
         return avg["w"][None], new_e["w"][None]
 
-    from repro.distributed.sharding import shard_map_compat
-    sharded = shard_map_compat(f, mesh=mesh,
-                               in_specs=(P("pod"), P("pod")),
-                               out_specs=(P("pod"), P("pod")),
-                               check_vma=False)
+    sharded = jax.shard_map(f, mesh=mesh,
+                            in_specs=(P("pod"), P("pod")),
+                            out_specs=(P("pod"), P("pod")),
+                            check_vma=False)
     avg, _ = sharded(g_local, jnp.zeros((2, 8)))
     np.testing.assert_allclose(np.asarray(avg), 2.0, rtol=1e-2)

@@ -94,6 +94,7 @@ ENTRY %main (p: f32[256,16]) -> f32[256,16] {
 
 
 def test_roofline_terms_and_bottleneck():
+    from repro.analysis.peaks import peaks_for
     from repro.analysis.roofline import HLOAnalysis
     a = HLOAnalysis(flops=197e12, hbm_bytes=819e9 / 2, ici_bytes=0,
                     dcn_bytes=0)
@@ -102,6 +103,17 @@ def test_roofline_terms_and_bottleneck():
     assert r.t_compute == pytest.approx(1.0)
     assert r.useful_ratio == pytest.approx(1.0)
     assert r.roofline_fraction == pytest.approx(1.0)
+
+
+def test_roofline_terms_name_their_device_and_refuse_unknown_kinds():
+    from repro.analysis.peaks import peaks_for
+    from repro.analysis.roofline import HLOAnalysis
+    a = HLOAnalysis(flops=1e12, hbm_bytes=1e9)
+    r = roofline_terms(a, model_flops_total=1e12, n_chips=1)
+    assert r.device_kind == "TPU v5 lite"
+    assert r.to_json()["device_kind"] == "TPU v5 lite"
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for("TPU v9 imaginary")
 
 
 def test_all_baseline_cells_present_and_ok():
