@@ -1,0 +1,11 @@
+"""Self wall ms per emulated second of ``operator``:
+SPE operator stages, device call included (engine profiler, bench/hostprof.py)."""
+from bench.hostprof import layer_ms_per_sim_s
+
+BUCKETS = ('operator',)
+
+
+def read(run):
+    per = layer_ms_per_sim_s(run["host"], run["host_sim_s"])
+    got = [per[b] for b in BUCKETS if b in per]
+    return sum(got) if got else None
