@@ -723,6 +723,9 @@ class Cluster:
         # sorted partition tuple); invalidated by the generation bump in
         # _assign (rebalance), never recomputed on the fetch hot path
         self._ap_cache: dict[tuple[str, str], tuple[int, tuple]] = {}
+        # engine profiler: True while a ``produce`` span is open, so a
+        # first send made inside it opens no second one
+        self.producing = False
 
     def _log(self, broker: str, topic: str, partition: int = 0
              ) -> ReplicaLog:
@@ -1010,6 +1013,19 @@ class Cluster:
             pend.retry_handle = h
 
     def _attempt_produce(self, bid: int) -> None:
+        # a send made outside any ``produce`` span (a retry, the next
+        # queued batch sent from an ack, a linger flush) is one of its own
+        prof = self.engine.profiler
+        if prof is None or self.producing:
+            self._send(bid)
+            return
+        t0 = time.perf_counter()
+        self.producing = True
+        self._send(bid)
+        self.producing = False
+        prof.add("produce", time.perf_counter() - t0)
+
+    def _send(self, bid: int) -> None:
         eng = self.engine
         now = eng.now
         pend = self._pending.get(bid)
@@ -1048,6 +1064,15 @@ class Cluster:
         eng.schedule(delay, lambda: self._broker_append(leader, bid))
 
     def _broker_append(self, broker: str, bid: int) -> None:
+        prof = self.engine.profiler
+        if prof is None:
+            self._leader_append(broker, bid)
+            return
+        t0 = time.perf_counter()
+        self._leader_append(broker, bid)
+        prof.add("append", time.perf_counter() - t0)
+
+    def _leader_append(self, broker: str, bid: int) -> None:
         eng = self.engine
         pend = self._pending.get(bid)
         if pend is None or pend.acked:
@@ -1090,6 +1115,8 @@ class Cluster:
     def _replicate(self, broker: str, pm: PartitionMeta,
                    records: list[Record], nbytes: int) -> None:
         eng = self.engine
+        prof = eng.profiler
+        t0 = time.perf_counter() if prof is not None else 0.0
         rep_rng = eng.client_rng("cluster:replication")
         first_off = records[0].offset
         # iterate in replicas order, not set order: the shared rep_rng
@@ -1109,6 +1136,7 @@ class Cluster:
             eng.monitor.broker_tx(broker, nbytes)
 
             def _deliver(b=b):
+                t1 = time.perf_counter() if prof is not None else 0.0
                 rl = self._log(b, pm.topic, pm.partition)
                 if rl.leo == first_off:       # in-order replication only
                     rl.append_batch(records)
@@ -1120,8 +1148,15 @@ class Cluster:
                             "replicate", pm.topic,
                             [now - r.produce_time for r in records])
                     self._maybe_commit(pm.topic, pm.partition)
+                elif prof is not None:
+                    # dropped and never resent: the follower keeps a gap
+                    prof.add("replicate_gap", 0.0)
+                if prof is not None:
+                    prof.add("replicate", time.perf_counter() - t1)
 
             eng.schedule(delay, _deliver)
+        if prof is not None and followers:
+            prof.add("replicate", time.perf_counter() - t0)
 
     def _maybe_commit(self, topic: str, partition: int = 0) -> None:
         """Advance HW to min(LEO) over the partition's ISR; wake waiters."""

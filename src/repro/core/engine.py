@@ -531,6 +531,8 @@ class Engine:
             out["profile_counts"] = {
                 "scheduler_pops": self.n_events,
                 "netem_path": self.net.n_path_queries,
+                # follower deliveries dropped out of order; 0 when none
+                "replicate_gap": 0,
                 **{k: prof.counts[k] for k in sorted(prof.counts)},
             }
             out["profile_wall"] = {

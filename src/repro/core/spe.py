@@ -76,6 +76,7 @@ from __future__ import annotations
 
 import collections
 import copy
+import sys
 import time
 from typing import Any, Callable, Optional
 
@@ -94,7 +95,8 @@ from repro.core.stubs import PER_BYTE_S, PER_RECORD_S
 from repro.core.subscription import DeliveryLoop
 
 __all__ = ["SPERuntime", "make_spe", "Query", "QUERIES", "jit_bucket",
-           "WINDOW_BASE_S", "build_event_chain", "elements_of"]
+           "WINDOW_BASE_S", "build_event_chain", "elements_of",
+           "StageParts", "stage_parts"]
 
 WINDOW_BASE_S = 200e-6
 
@@ -124,11 +126,127 @@ def _batch_nbytes(records) -> int:
 
 
 # ---------------------------------------------------------------------------
+# Engine-profiler spans of a query call
+# ---------------------------------------------------------------------------
+
+
+def _annotation(jax, name: str):
+    """An entered ``jax.profiler.TraceAnnotation``, or ``None`` where JAX
+    is not loaded (then there is no device trace to sit in)."""
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+class StageParts:
+    """The parts of one jitted stage call, timed as children of the
+    runtime's ``operator`` span.
+
+    A query marks where each part begins (:meth:`begin`); a part ends
+    where the next one begins, or at :meth:`end`, and is added to the
+    engine profiler under its name:
+
+    - ``stage_build``: decode and pad the inputs;
+    - ``stage_compile``: the first call of a jitted function new to this
+      query instance (trace, then compile or compile-cache load);
+    - ``stage_dispatch``: every later call;
+    - ``stage_wait``: ``block_until_ready``;
+    - ``stage_readback``: the results read back to Python.
+
+    Where JAX is loaded each part also runs under a
+    ``jax.profiler.TraceAnnotation`` of the same name, so that it sits
+    in a device trace on the trace's own clock.  The runtime hands one
+    out (``SPERuntime.stage_parts``) only while its ``operator`` span is
+    open; :func:`stage_parts` gives any other caller :data:`NO_PARTS`,
+    which records nothing.
+    """
+
+    __slots__ = ("prof", "jax", "name", "ann", "t0")
+
+    def __init__(self, prof, jax=None) -> None:
+        self.prof = prof
+        self.jax = jax
+        self.name: Optional[str] = None
+        self.ann = None
+        self.t0 = 0.0
+
+    def begin(self, name: str) -> None:
+        if self.prof is None:
+            return
+        self.end()
+        self.name = name
+        self.ann = _annotation(self.jax, name)
+        self.t0 = time.perf_counter()
+
+    def end(self) -> None:
+        if self.name is None:
+            return
+        self.prof.add(self.name, time.perf_counter() - self.t0)
+        self._close()
+
+    def drop(self) -> None:
+        """Close the open part unrecorded: the call reached no device."""
+        if self.name is not None:
+            self._close()
+
+    def _close(self) -> None:
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
+        self.name = None
+
+
+NO_PARTS = StageParts(None)
+
+
+def stage_parts(spe) -> StageParts:
+    """The stage parts of ``spe``'s open ``operator`` span, else
+    :data:`NO_PARTS` (a direct ``query(...)`` call records nothing)."""
+    return getattr(spe, "stage_parts", None) or NO_PARTS
+
+
+class OperatorSpan:
+    """The runtime's ``operator`` span around its query work, with the
+    profiler on: the wall time goes to ``prof`` on exit, the query's
+    :class:`StageParts` are open inside it, and where JAX is loaded the
+    whole span sits under a ``TraceAnnotation("operator")``."""
+
+    __slots__ = ("rt", "prof", "ann", "t0", "wall")
+
+    def __init__(self, rt, prof) -> None:
+        self.rt = rt
+        self.prof = prof
+        self.wall = 0.0
+
+    def __enter__(self) -> "OperatorSpan":
+        jax = sys.modules.get("jax")
+        self.ann = _annotation(jax, "operator")
+        self.rt.stage_parts = StageParts(self.prof, jax)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, *_) -> None:
+        parts, self.rt.stage_parts = self.rt.stage_parts, None
+        if exc_type is None:
+            parts.end()
+        else:
+            parts.drop()
+        self.wall = time.perf_counter() - self.t0
+        self.prof.add("operator", self.wall)
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+
+
+# ---------------------------------------------------------------------------
 # SPE runtime
 # ---------------------------------------------------------------------------
 
 
 class SPERuntime(DeliveryLoop):
+    stage_parts: Optional[StageParts] = None   # set inside ``operator``
+
     def __init__(self, comp: Component, host: str):
         self.init_subscriber(
             comp, host, [comp.get("inTopic") or comp.get("topic")])
@@ -231,12 +349,15 @@ class SPERuntime(DeliveryLoop):
         nbytes = _batch_nbytes(records)
         service = (WINDOW_BASE_S + PER_RECORD_S * len(records)
                    + PER_BYTE_S * nbytes)
-        t0 = time.perf_counter()
-        results = self.query(self, eng, records)   # REAL compute, now
-        wall = time.perf_counter() - t0
         prof = eng.profiler
-        if prof is not None:
-            prof.add("operator", wall)
+        if prof is None:
+            t0 = time.perf_counter()
+            results = self.query(self, eng, records)   # REAL compute, now
+            wall = time.perf_counter() - t0
+        else:
+            with OperatorSpan(self, prof) as span:
+                results = self.query(self, eng, records)
+            wall = span.wall
         self.n_processed += len(records)
         if self.query.measure_wall:
             eng.monitor.event(eng.now, "spe_exec", spe=self.name,
@@ -272,31 +393,11 @@ class SPERuntime(DeliveryLoop):
 
     def _ingest_event(self, eng, records) -> None:
         prof = eng.profiler
-        t_prof = time.perf_counter() if prof is not None else 0.0
-        if isinstance(records, BatchView):
-            outs, n_late = self._ingest_view(records)
+        if prof is None:
+            outs = self._event_chain(eng, records)
         else:
-            elems: list[Element] = []
-            n_late = 0
-            for r in records:
-                et = r.event_time
-                prev = self._maxet.get(r.partition)
-                if prev is not None and et + self.allowed_lateness < prev:
-                    n_late += 1        # late by its own partition's clock
-                    continue
-                if prev is None or et > prev:
-                    self._maxet[r.partition] = et
-                elems.append(Element(r.payload, r.size, et, r.key))
-            outs = self.chain.process(elems, self._ctx)
-        if n_late:
-            self.late_records += n_late
-            eng.monitor.event(eng.now, "late_records", spe=self.name,
-                              n=n_late)
-        self.n_processed += len(records)
-        outs += self.chain.advance_watermark(self._watermark(eng),
-                                             self._ctx)
-        if prof is not None:
-            prof.add("operator", time.perf_counter() - t_prof)
+            with OperatorSpan(self, prof):
+                outs = self._event_chain(eng, records)
         nbytes = _batch_nbytes(records)
         service = (WINDOW_BASE_S + PER_RECORD_S * len(records)
                    + PER_BYTE_S * nbytes)
@@ -321,6 +422,33 @@ class SPERuntime(DeliveryLoop):
         tel = eng.telemetry
         if tel is not None:
             tel.span("operator", self.in_topic, done - eng.now)
+
+    def _event_chain(self, eng, records) -> list[Element]:
+        """Lateness, the chain and the watermark: the ``operator`` work
+        of one event-time batch."""
+        if isinstance(records, BatchView):
+            outs, n_late = self._ingest_view(records)
+        else:
+            elems: list[Element] = []
+            n_late = 0
+            for r in records:
+                et = r.event_time
+                prev = self._maxet.get(r.partition)
+                if prev is not None and et + self.allowed_lateness < prev:
+                    n_late += 1        # late by its own partition's clock
+                    continue
+                if prev is None or et > prev:
+                    self._maxet[r.partition] = et
+                elems.append(Element(r.payload, r.size, et, r.key))
+            outs = self.chain.process(elems, self._ctx)
+        if n_late:
+            self.late_records += n_late
+            eng.monitor.event(eng.now, "late_records", spe=self.name,
+                              n=n_late)
+        self.n_processed += len(records)
+        outs += self.chain.advance_watermark(self._watermark(eng),
+                                             self._ctx)
+        return outs
 
     def _ingest_view(self, view: BatchView) -> tuple[list[Element], int]:
         """Columnar event-time ingest: per-partition lateness against the
@@ -808,9 +936,12 @@ class TrafficMetricsQuery(Query):
         return self._jit_cache[n]
 
     def _run(self, spe, eng, records):
+        parts = stage_parts(spe)
+        parts.begin("stage_build")
         pkts = [self._data(r) for r in records]
         pkts = [p for p in pkts if isinstance(p, dict) and "service" in p]
         if not pkts:
+            parts.drop()
             return []
         n = jit_bucket(len(pkts))                        # pad: stable shapes
         sids = np.zeros((n,), np.int32)
@@ -820,16 +951,21 @@ class TrafficMetricsQuery(Query):
             sids[i] = self._sid.get(p["service"], 0)
             sizes[i] = float(p.get("bytes", 0))
             valid[i] = True
+        unit = self._unit(records)
+        parts.begin("stage_dispatch" if n in self._jit_cache
+                    else "stage_compile")
         f = self._metrics_fn(n)
         conns, bw, top = f(sids, sizes, valid)
+        parts.begin("stage_wait")
         conns.block_until_ready()
+        parts.begin("stage_readback")
         payload = {
             "connections": {s: float(conns[i])
                             for s, i in self._sid.items()},
             "bandwidth": {s: float(bw[i]) for s, i in self._sid.items()},
         }
-        return [self._wrap(payload, 8 * len(self.services),
-                           self._unit(records))]
+        parts.end()
+        return [self._wrap(payload, 8 * len(self.services), unit)]
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ Consumer types:
 from __future__ import annotations
 
 import os
+import time
 from typing import Any, Optional
 
 import numpy as np
@@ -71,6 +72,10 @@ class ProducerBase:
                 unit: Optional[Any] = None,
                 key: Optional[Any] = None,
                 event_time: Optional[float] = None) -> None:
+        prof = eng.profiler
+        if prof is not None:
+            t0 = time.perf_counter()
+            eng.cluster.producing = True
         if unit is not None:
             eng.monitor.event(eng.now, "unit_in", unit=unit)
             payload = {"unit": unit, "data": payload}
@@ -87,6 +92,9 @@ class ProducerBase:
                             batch_bytes=self.batch_bytes,
                             event_time=event_time)
         self.sent += 1
+        if prof is not None:
+            eng.cluster.producing = False
+            prof.add("produce", time.perf_counter() - t0)
 
 
 class SFSTProducer(ProducerBase):
@@ -266,6 +274,8 @@ class ConsumerBase(DeliveryLoop):
         ep = self._bp_epoch
 
         def _done():
+            prof = eng.profiler
+            t0 = time.perf_counter() if prof is not None else 0.0
             for p in payloads_of(records):
                 if isinstance(p, dict) and "unit" in p:
                     eng.monitor.event(eng.now, "unit_out", unit=p["unit"])
@@ -287,6 +297,8 @@ class ConsumerBase(DeliveryLoop):
             self.handle(eng, records)
             if self.queue_bytes_max > 0:
                 self.bp_drain(eng, nbytes, ep)
+            if prof is not None:
+                prof.add("consume", time.perf_counter() - t0)
 
         self.busy_until = eng.execute_on(self.host, cost, _done)
 

@@ -27,8 +27,9 @@ unbounded per-delivery latency list as the always-on store behind
    exportable as Chrome trace-event JSON via :mod:`repro.obs.trace`.
 
 4. **Engine profiler** — opt-in (``profile=True``) wall-clock phase
-   accounting (scheduler pops, netem path queries, fetch/deliver,
-   operator processing, checkpoints).  Wall times are nondeterministic
+   accounting (scheduler pops, netem path queries, produce, append,
+   replicate, fetch/deliver, consume, operator processing and its stage
+   parts, checkpoints).  Wall times are nondeterministic
    and excluded from the fingerprint (``profile_wall`` is in
    ``repro.sweep.results.TIMING_KEYS``); the per-phase *call counts* are
    deterministic and fingerprinted (``profile_counts``).
@@ -264,6 +265,17 @@ class Profiler:
     ``fetch_ctl`` and ``fetch_take`` are identical across
     fused/legacy, ``deliver_cohort`` and ``scheduler_pops`` are the
     intentional event-count deltas.
+
+    Produce-path buckets: ``produce`` (a producer's send, or a send made
+    outside one: retry, queued batch, linger flush), ``append`` (the
+    leader's append, ack and commit), ``replicate`` (the fan-out to
+    followers and each follower's append) and ``consume`` (a consumer's
+    completion work).  A stage call splits its ``operator`` span into
+    ``stage_build``, ``stage_compile``, ``stage_dispatch``,
+    ``stage_wait`` and ``stage_readback`` (``core/spe.StageParts``).
+    Spans nest: a child's wall is also in its parent's.  One counter
+    has no wall: ``replicate_gap``, the follower deliveries dropped
+    because they arrived out of order.
     """
 
     __slots__ = ("counts", "wall")
