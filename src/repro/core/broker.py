@@ -45,10 +45,12 @@ per (producer, topic, partition) into a pending batch that is flushed when
 the linger timer fires or ``batch_bytes`` is reached (Kafka ``linger.ms``
 / ``batch.size``).  A flushed batch runs the attempt/ack/retry state
 machine *once* — one leader append, one ack, one retry timer, one
-replication transfer per follower — instead of once per record, and is
-appended through the vectorized :meth:`RecordBatch.extend_rows`.
+replication transfer per follower — instead of once per record.
 ``linger_s == 0`` flushes a one-record batch immediately and reproduces
-the legacy per-record event pattern exactly.
+the legacy per-record event pattern exactly.  Each replica (the leader
+and every follower) appends a batch row by row with scalar stores
+(:meth:`RecordBatch.append_row`), which costs a lone record a quarter of
+what numpy slice stores and a ``cumsum`` would.
 
 Brokers are in-memory (the paper's accuracy experiments do not exercise
 disk).  Each per-(broker, topic, partition) log is a **columnar**
@@ -81,7 +83,6 @@ semantics (``core/spe.py``).
 """
 from __future__ import annotations
 
-import dataclasses
 import time
 import zlib
 from bisect import bisect_left, bisect_right
@@ -141,6 +142,13 @@ class Record:
     # watermarks from this column, never from arrival times.
     event_time: float = 0.0
 
+    def stamped(self, offset: int, epoch: int) -> "Record":
+        """A copy at ``offset`` under ``epoch``: ``dataclasses.replace``
+        without its per-call field walk (the replica append's copies)."""
+        return Record(self.msg_id, self.topic, self.payload, self.size,
+                      self.produce_time, self.producer, offset, epoch,
+                      self.partition, self.key, self.event_time)
+
 
 class RecordBatch:
     """Columnar append-only log: numpy columns + payload/key lists.
@@ -177,8 +185,8 @@ class RecordBatch:
 
     # -- growth --------------------------------------------------------
 
-    def _grow(self, min_cap: int = 0) -> None:
-        cap = max(self._MIN_CAP, 2 * len(self.msg_id), min_cap)
+    def _grow(self) -> None:
+        cap = max(self._MIN_CAP, 2 * len(self.msg_id))
         for name in self._COLS:
             col = getattr(self, name)
             new = np.empty(cap, col.dtype)
@@ -199,45 +207,15 @@ class RecordBatch:
         self.epoch[i] = epoch
         self.event_time[i] = (produce_time if event_time is None
                               else event_time)
-        total = size + (self.cum_list[i - 1] if i else 0)
+        # int(): the prefix-sum mirror holds python ints whatever numeric
+        # type the size came in
+        total = int(size) + (self.cum_list[i - 1] if i else 0)
         self.cum_size[i] = total
         self.cum_list.append(total)
         self.payloads.append(payload)
         self.producers.append(producer)
         self.keys.append(key)
         self.n = i + 1
-        return i
-
-    def extend_rows(self, msg_ids, sizes, produce_times, epochs,
-                    payloads: list, producers: list,
-                    keys: Optional[list] = None,
-                    event_times: Optional[list] = None) -> int:
-        """Vectorized multi-row append; returns the first offset.
-
-        Column arguments are sequences of equal length ``k``; the prefix
-        sum is extended with one ``cumsum`` instead of ``k`` scalar adds
-        (the produce batcher's append path).
-        """
-        k = len(payloads)
-        if k == 0:
-            return self.n
-        i = self.n
-        if i + k > len(self.msg_id):
-            self._grow(min_cap=i + k)
-        self.msg_id[i:i + k] = msg_ids
-        self.size[i:i + k] = sizes
-        self.produce_time[i:i + k] = produce_times
-        self.epoch[i:i + k] = epochs
-        self.event_time[i:i + k] = (produce_times if event_times is None
-                                    else event_times)
-        base = self.cum_list[i - 1] if i else 0
-        cs = base + np.cumsum(np.asarray(sizes, np.int64))
-        self.cum_size[i:i + k] = cs
-        self.cum_list.extend(cs.tolist())
-        self.payloads.extend(payloads)
-        self.producers.extend(producers)
-        self.keys.extend(keys if keys is not None else [None] * k)
-        self.n = i + k
         return i
 
     # -- O(1)/O(slice) accounting --------------------------------------
@@ -612,25 +590,23 @@ class ReplicaLog:
                                         self.partition)
 
     def append(self, rec: Record) -> Record:
-        off = self.batch.append_row(rec.msg_id, rec.size, rec.produce_time,
-                                    rec.epoch, rec.payload, rec.producer,
-                                    rec.key, event_time=rec.event_time)
-        return dataclasses.replace(rec, offset=off)
+        return self.append_batch([rec])[0]
 
     def append_batch(self, records: list[Record],
                      epoch: Optional[int] = None) -> list[Record]:
-        """Vectorized append; returns offset-stamped (epoch-stamped) copies."""
-        k = len(records)
-        epochs = ([epoch] * k if epoch is not None
-                  else [r.epoch for r in records])
-        first = self.batch.extend_rows(
-            [r.msg_id for r in records], [r.size for r in records],
-            [r.produce_time for r in records], epochs,
-            [r.payload for r in records], [r.producer for r in records],
-            [r.key for r in records],
-            [r.event_time for r in records])
-        return [dataclasses.replace(r, offset=first + j, epoch=epochs[j])
-                for j, r in enumerate(records)]
+        """Append ``records`` row by row; return offset-stamped copies.
+
+        ``epoch`` stamps every row and copy (the leader's epoch); ``None``
+        keeps each record's own (a follower's append).
+        """
+        append_row = self.batch.append_row
+        out = []
+        for r in records:
+            e = r.epoch if epoch is None else epoch
+            off = append_row(r.msg_id, r.size, r.produce_time, e, r.payload,
+                             r.producer, r.key, r.event_time)
+            out.append(r.stamped(off, e))
+        return out
 
     def truncate_to(self, other: "ReplicaLog") -> list[Record]:
         """Make this log a copy of ``other``; return locally-lost records."""

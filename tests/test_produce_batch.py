@@ -1,47 +1,19 @@
-"""Produce batcher: vectorized extend_rows, linger/batch_bytes flush
-semantics, one ack/retry timer per batch, and delivery equivalence with
-the legacy per-record path.
+"""Produce batcher: the row-by-row replica append, linger/batch_bytes
+flush semantics, one ack/retry timer per batch, and delivery equivalence
+with the legacy per-record path.
 """
+import dataclasses
+
 import numpy as np
+import pytest
 
 from repro.core import Engine, PipelineSpec, RecordBatch
 from repro.core.broker import Record, ReplicaLog
 
 
 # ---------------------------------------------------------------------------
-# RecordBatch.extend_rows
+# ReplicaLog.append_batch
 # ---------------------------------------------------------------------------
-
-
-def test_extend_rows_matches_append_row():
-    a, b = RecordBatch(), RecordBatch()
-    rows = [(i + 1, 10 * (i + 1), 0.1 * i, i % 2, {"i": i}, f"p{i % 3}",
-             f"k{i % 4}") for i in range(9)]
-    for r in rows:
-        a.append_row(*r)
-    b.extend_rows([r[0] for r in rows], [r[1] for r in rows],
-                  [r[2] for r in rows], [r[3] for r in rows],
-                  [r[4] for r in rows], [r[5] for r in rows],
-                  [r[6] for r in rows])
-    assert a.n == b.n == 9
-    for col in ("msg_id", "size", "produce_time", "epoch", "cum_size"):
-        assert np.array_equal(getattr(a, col)[:9], getattr(b, col)[:9])
-    assert a.payloads == b.payloads
-    assert a.producers == b.producers
-    assert a.keys == b.keys
-    assert b.total_bytes() == sum(r[1] for r in rows)
-
-
-def test_extend_rows_grows_and_chains_prefix_sum():
-    b = RecordBatch()
-    b.append_row(1, 5, 0.0, 0, "x", "p")
-    n = 3 * RecordBatch._MIN_CAP          # force capacity growth
-    first = b.extend_rows(list(range(2, n + 2)), [7] * n, [0.0] * n,
-                          [0] * n, ["y"] * n, ["p"] * n)
-    assert first == 1 and b.n == n + 1
-    assert b.total_bytes() == 5 + 7 * n
-    assert b.bytes_between(1, n + 1) == 7 * n
-    assert b.extend_rows([], [], [], [], [], []) == b.n   # no-op append
 
 
 def test_replica_log_append_batch_stamps_offsets_and_epoch():
@@ -54,6 +26,70 @@ def test_replica_log_append_batch_stamps_offsets_and_epoch():
     assert rl.leo == 4
     assert all(r.partition == 2 for r in rl.records)
     assert all(r.key == "k" for r in rl.records)
+
+
+def _records(k, first_id):
+    # sizes alternate python and numpy ints, epochs differ per record and
+    # event times differ from produce times, so every column is exercised
+    return [Record(first_id + i, "t", {"i": i}, (np.int64 if i % 2 else int)
+                   (10 + 3 * i), 0.25 * i, f"p{i % 3}", epoch=i % 4,
+                   partition=1, key=f"k{i % 5}" if i % 2 else None,
+                   event_time=0.25 * i - 0.1)
+            for i in range(k)]
+
+
+@pytest.mark.parametrize("prefill", [0, 63])
+@pytest.mark.parametrize("epoch", [7, None])
+@pytest.mark.parametrize("k", [1, 2, 3, 63, 64, 65, 129])
+def test_append_batch_matches_a_plain_reference(k, epoch, prefill):
+    """The log and the copies ``append_batch`` leaves equal ones built
+    in bulk from the same records, across the growth boundaries."""
+    rl = ReplicaLog("t", partition=1)
+    for i in range(prefill):
+        rl.batch.append_row(i, 7, 0.5, 0, i, "q")
+    recs = _records(k, first_id=1000)
+    out = rl.append_batch(recs, epoch=epoch)
+    ref = [dataclasses.replace(r, offset=prefill + j,
+                               epoch=r.epoch if epoch is None else epoch)
+           for j, r in enumerate(recs)]
+    assert out == ref
+    assert all(o is not r for o, r in zip(out, recs))     # copies
+    assert rl.records[prefill:] == ref
+
+    b, n = rl.batch, prefill + k
+    sizes = [7] * prefill + [int(r.size) for r in recs]
+    want = {
+        "msg_id": list(range(prefill)) + [r.msg_id for r in recs],
+        "size": sizes,
+        "produce_time": [0.5] * prefill + [r.produce_time for r in recs],
+        "epoch": [0] * prefill + [r.epoch for r in ref],
+        "event_time": [0.5] * prefill + [r.event_time for r in recs],
+        "cum_size": np.cumsum(sizes).tolist(),
+    }
+    cap = RecordBatch._MIN_CAP
+    while cap < n:
+        cap *= 2                                       # doubling growth
+    fresh = RecordBatch()
+    assert b.n == n
+    for col, values in want.items():
+        x = getattr(b, col)
+        assert len(x) == cap, col
+        assert x.dtype == getattr(fresh, col).dtype, col
+        assert np.array_equal(x[:n], np.asarray(values, x.dtype)), col
+    assert b.cum_list == want["cum_size"]
+    assert all(type(c) is int for c in b.cum_list)
+    assert b.payloads == list(range(prefill)) + [r.payload for r in recs]
+    assert b.producers == ["q"] * prefill + [r.producer for r in recs]
+    assert b.keys == [None] * prefill + [r.key for r in recs]
+
+
+def test_stamped_copy_carries_every_record_field():
+    # a distinct value per field, so a field the copy drops or swaps shows
+    vals = {f.name: object() for f in dataclasses.fields(Record)}
+    rec = Record(**vals)
+    out = rec.stamped(offset=5, epoch=9)
+    assert out is not rec
+    assert out == dataclasses.replace(rec, offset=5, epoch=9)
 
 
 # ---------------------------------------------------------------------------
