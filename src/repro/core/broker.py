@@ -618,6 +618,59 @@ class ReplicaLog:
         return lost
 
 
+def fair_takes(logs: list, owed: int, budget: int, cap: int,
+               queue_empty: bool) -> tuple[list[int], list[int]]:
+    """Rows and bytes each partition takes in one fetch cycle of a
+    bounded subscriber (the rule: :meth:`Cluster._fetch`).
+
+    ``logs``: per partition in the cycle's order, ``(batch, off, hw)``:
+    the log's :class:`RecordBatch` and its committed rows ``[off, hw)``
+    past the group's offset, or ``None`` where it has none.  The first
+    ``owed`` partitions are those the last capped cycle passed over.
+    """
+    n = len(logs)
+    rows = [0] * n
+    used = [0] * n
+    left = budget
+
+    def nxt(j):                 # bytes of j's next record, 0 if none
+        if logs[j] is None:
+            return 0
+        batch, off, hw = logs[j]
+        i = off + rows[j]
+        return batch.bytes_between(i, i + 1) if i < hw else 0
+
+    # pass 1: the next record of each owed partition, else of the lead
+    debtors = [j for j in range(owed) if nxt(j)]
+    if not debtors:
+        debtors = [j for j in range(n) if nxt(j)][:1]
+    for k, j in enumerate(debtors):
+        head = nxt(j)
+        if head > left:
+            if k:
+                continue
+            # the first record owed is larger than the budget: into an
+            # empty queue it goes alone (the documented overshoot of a
+            # record larger than the whole bound); otherwise nothing is
+            # taken (hold), so the queue drains to it
+            if queue_empty:
+                rows[j], used[j] = 1, head
+            return rows, used
+        rows[j], used[j] = 1, head
+        left -= head
+    # pass 2: what is left, split evenly over the partitions with rows,
+    # in order; a partition's unused share passes to those after it
+    todo = [j for j in range(n) if nxt(j)]
+    for k, j in enumerate(todo):
+        batch, off, hw = logs[j]
+        m, got = batch.take_within_bytes(
+            off + rows[j], hw, min(cap - used[j], left // (len(todo) - k)))
+        rows[j] += m
+        used[j] += got
+        left -= got
+    return rows, used
+
+
 class Cluster:
     """Controller + brokers.  All timing flows through ``engine.schedule``."""
 
@@ -699,6 +752,9 @@ class Cluster:
         # sorted partition tuple); invalidated by the generation bump in
         # _assign (rebalance), never recomputed on the fetch hot path
         self._ap_cache: dict[tuple[str, str], tuple[int, tuple]] = {}
+        # bounded subscribers: (topic, consumer) -> (rotation point in
+        # the assignment, partitions owed) for the next fetch cycle
+        self._fair_state: dict[tuple[str, str], tuple[int, tuple]] = {}
         # engine profiler: True while a ``produce`` span is open, so a
         # first send made inside it opens no second one
         self.producing = False
@@ -1196,6 +1252,41 @@ class Cluster:
         fused groups consecutive equal-landing-time responses into
         cohort events (``t_land`` is non-decreasing across the loop, so
         equal values are always adjacent — see kernels/cohort.py).
+
+        Unbounded subscribers walk their assignment in order and take up
+        to ``fetch_bytes`` a partition.  A bounded subscriber
+        (``fetch_budget()`` is not None: the ``pause`` policy) plans the
+        cycle first (:func:`fair_takes`), from the leaders' logs and the
+        budget it has when the cycle starts:
+
+        - the partitions the last capped cycle passed over (owed) are
+          walked first, in the order it walked them; then the others,
+          from a rotation point in the assignment.  The per-partition
+          RNG draws follow that order;
+        - each owed partition takes its next record first, while the
+          bytes left hold it; with none owed, the first partition with
+          rows (the lead) does.  When the first of these records is
+          larger than the whole budget, nothing is taken (hold) and the
+          subscriber pauses until its queue drains; into an empty queue
+          the record goes alone instead (the documented overshoot of a
+          record larger than the whole bound);
+        - the bytes left are then split evenly, in order: a partition
+          with rows takes at most ``left // k`` more, ``k`` counting it
+          and those after it with rows.  No partition takes another's
+          share, and bytes one leaves pass on to those after it;
+        - takes are strict: a partition takes at most its planned rows
+          and bytes, from whichever broker its client reads (a deposed
+          leader's log may differ from the plan's), so admitted but
+          unprocessed bytes never exceed ``queueBytes`` (``bp_reserve``
+          raises otherwise), and nothing is shed;
+        - a partition with rows that takes nothing is passed over.  The
+          subscriber is then flagged starved (paused until a drain) when
+          its queue holds bytes, else it retries at its poll interval;
+        - a cycle that leaves committed rows behind is capped: the next
+          owes its passed-over partitions and starts its rotation one
+          position further.  So the leftover and the lead rotate, and no
+          partition with a backlog is passed over by two capped cycles
+          in a row unless the records owed exceed the budget.
         """
         eng = self.engine
         rng = eng.client_rng(consumer.name)
@@ -1251,14 +1342,35 @@ class Cluster:
         belief = self._belief
         cap = self._fetch_bytes
         fb = getattr(consumer, "fetch_budget", None)
+        budget = fb() if fb is not None else None
         inflight = self._inflight_until
         ikey = (topic, cname)
+        want = None
+        if budget is not None:
+            t0 = time.perf_counter() if prof is not None else 0.0
+            rot, owed = self._fair_state.get(ikey, (0, ()))
+            owed = [p for p in owed if p in parts]
+            rot %= len(parts)
+            parts = owed + [p for p in parts[rot:] + parts[:rot]
+                            if p not in owed]
+            plan = []
+            for p in parts:
+                log = logs[pms[p].leader].get((topic, p))
+                off = offs[(topic, p, owner)]
+                plan.append((log.batch, off, log.hw)
+                            if log is not None and off < log.hw else None)
+            want, want_bytes = fair_takes(plan, len(owed), budget, cap,
+                                          consumer.queue_empty())
+            passed: list = []
+            n_capped = 0
+            if prof is not None:
+                prof.add("backpressure", time.perf_counter() - t0)
         fused = self._fused
         pend: Optional[list] = [] if fused else None
         tx_hosts: list = []             # per-response leader/bytes for
         tx_bytes: list = []             # one batched broker_tx tally
         any_more = any_blocked = any_delivered = False
-        for part in parts:
+        for j, part in enumerate(parts):
             # -- control phase: metadata resolution + request RTT ------
             t0 = time.perf_counter() if prof is not None else 0.0
             pm = pms[part]
@@ -1299,35 +1411,27 @@ class Cluster:
                 continue                            # drained partition
             batchlog = log.batch
             # fetch.max.bytes caps one response (remainder next fetch);
-            # a bounded subscriber (pause policy) additionally caps the
-            # take at its remaining ingest budget (strict — see
-            # take_within_bytes), byte-identical to the legacy path at
-            # the budget=None default
-            budget = fb() if fb is not None else None
-            if budget is None:
+            # a bounded subscriber (pause policy) takes what the cycle's
+            # plan gives it instead (strict — see fair_takes)
+            if want is None:
                 n, nbytes = batchlog.take_by_bytes(off, hw, cap)
             else:
+                # at most the planned rows and bytes: the client's leader
+                # may be a deposed one whose log differs from the plan's
                 n, nbytes = batchlog.take_within_bytes(
-                    off, hw, min(cap, budget))
+                    off, min(hw, off + want[j]), want_bytes[j])
+                if prof is not None and off + n < hw and \
+                        batchlog.bytes_between(off, off + n + 1) <= cap:
+                    n_capped += 1       # the budget left rows behind
                 if n == 0:
-                    if consumer.queue_empty():
-                        # a single record larger than the bound:
-                        # deliver it anyway rather than deadlock
-                        # (documented overshoot)
-                        n, nbytes = batchlog.take_by_bytes(
-                            off, hw, min(cap, budget))
-                    else:
-                        # committed rows remain but the budget cannot
-                        # admit the next one: flag the subscriber
-                        # starved so its loop parks paused instead of
-                        # busy-polling; report byte-capped so no waiter
-                        # is parked either way
-                        consumer.bp_starve()
-                        any_more = True
-                        if prof is not None:
-                            prof.add("fetch_take",
-                                     time.perf_counter() - t1)
-                        continue
+                    # passed over: committed rows remain but the plan
+                    # admits none; report byte-capped so no waiter is
+                    # parked (see the starve flag below)
+                    passed.append(part)
+                    any_more = True
+                    if prof is not None:
+                        prof.add("fetch_take", time.perf_counter() - t1)
+                    continue
             delay, lost = net.transfer(leader, chost, nbytes, rng)
             if delay is None or lost:
                 any_blocked = True
@@ -1335,7 +1439,7 @@ class Cluster:
                     prof.add("fetch_take", time.perf_counter() - t1)
                 continue
             offs[okey] = off + n
-            if budget is not None:
+            if want is not None:
                 consumer.bp_reserve(nbytes)
             tx_hosts.append(leader)
             tx_bytes.append(nbytes)
@@ -1392,6 +1496,18 @@ class Cluster:
                     group[0][0] - now,
                     lambda g=group:
                         self._deliver_cohort(consumer, topic, g))
+        if want is not None:
+            if passed and not consumer.queue_empty():
+                # flag the subscriber starved so its loop parks paused
+                # until a drain instead of busy-polling
+                consumer.bp_starve()
+            if any_more:
+                # a capped cycle: the next owes what this one passed
+                # over and starts its rotation one position further
+                self._fair_state[ikey] = ((rot + 1) % len(parts),
+                                          tuple(passed))
+            if prof is not None and n_capped:
+                prof.add("fetch_capped", 0.0, n=n_capped)
         if any_more:
             return FETCH_DELIVERED_MORE
         if any_blocked:

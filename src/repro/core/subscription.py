@@ -32,6 +32,8 @@ the processing completes (``_busy_horizon``).
 """
 from __future__ import annotations
 
+import time
+
 from repro.core.broker import (
     FETCH_DELIVERED, FETCH_DELIVERED_MORE, FETCH_EMPTY, BatchView,
 )
@@ -106,7 +108,8 @@ class DeliveryLoop:
     # processing.  Under the default ``pause`` policy the *fetch side*
     # is throttled: ``fetch_budget`` caps the broker's take (strict —
     # never overshoots, except for a single record larger than the whole
-    # bound) and a full queue parks the delivery loop in a third state —
+    # bound), split fairly over the owned partitions (``Cluster._fetch``),
+    # and a full queue parks the delivery loop in a third state —
     # paused — replacing both the scheduled-event and the cluster-waiter
     # legs of the invariant; ``bp_drain`` resumes it with a zero-delay
     # fetch.  Shed policies instead fetch normally and drop at
@@ -125,8 +128,17 @@ class DeliveryLoop:
 
     def bp_reserve(self, nbytes: int) -> None:
         """Account bytes taken by the broker on our behalf (pause
-        policy: the reservation covers in-flight + queued bytes)."""
+        policy: the reservation covers in-flight + queued bytes).
+
+        The bound is strict: a take that would carry a non-empty queue
+        past ``queueBytes`` is a broker fault and raises."""
         if self.queue_bytes_max > 0 and self.shed_policy == "pause":
+            if self._q_used and \
+                    self._q_used + nbytes > self.queue_bytes_max:
+                raise RuntimeError(
+                    f"{self.name}: a take of {nbytes} B onto "
+                    f"{self._q_used} B queued breaks queueBytes "
+                    f"{self.queue_bytes_max}")
             self._q_used += nbytes
             if self._q_used > self._q_peak:
                 self._q_peak = self._q_used
@@ -141,6 +153,8 @@ class DeliveryLoop:
         self._bp_starved = True
 
     def _bp_pause(self, eng, key) -> None:
+        prof = eng.profiler
+        t0 = time.perf_counter() if prof is not None else 0.0
         if key not in self._bp_paused:
             self._bp_paused[key] = eng.now
             self.n_pauses += 1
@@ -148,14 +162,20 @@ class DeliveryLoop:
             if tel is not None:
                 tel.flight(eng.now, "bp_pause", sub=self.name,
                            queued_bytes=self._q_used)
+        if prof is not None:
+            prof.add("backpressure", time.perf_counter() - t0)
 
     def bp_drain(self, eng, nbytes: int, epoch=None) -> None:
         """Release queue bytes after processing; resume paused loops."""
         if epoch is not None and epoch != self._bp_epoch:
             return      # reserved before a reset: already zeroed
+        prof = eng.profiler
+        t0 = time.perf_counter() if prof is not None else 0.0
         self._q_used = max(0, self._q_used - nbytes)
         if self._bp_paused and self._q_used < self.queue_bytes_max:
             self._bp_resume(eng)
+        if prof is not None:
+            prof.add("backpressure", time.perf_counter() - t0)
 
     def _bp_resume(self, eng) -> None:
         paused, self._bp_paused = self._bp_paused, {}

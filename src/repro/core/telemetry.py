@@ -273,9 +273,14 @@ class Profiler:
     completion work).  A stage call splits its ``operator`` span into
     ``stage_build``, ``stage_compile``, ``stage_dispatch``,
     ``stage_wait`` and ``stage_readback`` (``core/spe.StageParts``).
-    Spans nest: a child's wall is also in its parent's.  One counter
-    has no wall: ``replicate_gap``, the follower deliveries dropped
-    because they arrived out of order.
+    Bounded ingest (``queueBytes``): ``backpressure`` spans the budget
+    split at the start of a ``pause`` subscriber's fetch cycle, a pause
+    (``_bp_pause``) and a drain with its resume (``bp_drain``).
+    Spans nest: a child's wall is also in its parent's.  Two counters
+    have no wall: ``replicate_gap``, the follower deliveries dropped
+    because they arrived out of order; and ``fetch_capped``, the takes
+    a bounded subscriber's budget cut short with committed rows left
+    (its pauses are ``Engine.metrics()``'s ``backpressure_pauses``).
     """
 
     __slots__ = ("counts", "wall")
