@@ -1155,10 +1155,9 @@ class Cluster:
         # stream makes follower order part of the deterministic contract
         # (ISR is always a subset of replicas), and set order varies with
         # per-process hash randomization — sweep caching would diverge.
-        # The fan-out is one homogeneous (src, nbytes) cohort, so the
-        # delay arithmetic runs as a single vectorized transfer_many
-        # (bit-identical to per-follower transfer calls, RNG order
-        # included).
+        # The fan-out is one homogeneous (src, nbytes) cohort, answered
+        # by one transfer_many (per-follower transfer calls inside one
+        # netem_path span, RNG order included).
         followers = [x for x in pm.replicas if x in pm.isr and x != broker]
         for b, (delay, lost) in zip(
                 followers,

@@ -53,8 +53,6 @@ import numpy as np
 from scipy.sparse import csr_matrix
 from scipy.sparse.csgraph import dijkstra as _apsp_dijkstra
 
-from repro.kernels import netcalc
-
 
 @dataclass
 class LinkCfg:
@@ -313,13 +311,6 @@ class _RouteTables:
         self.keep_epoch = loss_epoch
         # keep factors ride the merged scalar row cache — drop it all
         self._row_cache.clear()
-
-    def keep_row(self, net: "Network", si: int) -> np.ndarray:
-        """Keep-probability row for one source (rebuilds the matrix if
-        a gray-loss transition moved ``loss_epoch``)."""
-        if self.keep_epoch != net.loss_epoch:
-            self._rebuild_keep(net.loss_epoch)
-        return self.KEEP[si]
 
     def hop_path(self, net: "Network", si: int,
                  di: int) -> Optional[list[str]]:
@@ -664,53 +655,17 @@ class Network:
 
         Bit-identical to calling :meth:`transfer` once per destination
         in order — same counters, same single-draw-per-live-destination
-        RNG order — but the delay arithmetic for the whole cohort runs
-        as one vectorized :mod:`repro.kernels.netcalc` computation in
-        table mode (the broker's replication fan-out rides this).
+        RNG order.  In table mode the cohort is answered by
+        :meth:`_transfer_t` per destination inside one accounted call
+        (the broker's replication fan-out rides this).
         """
         if not self._use_tables():
             return [self.transfer(src, d, nbytes, rng) for d in dsts]
-        return self._accounted(self._transfer_many_t, src, dsts,
-                               nbytes, rng)
-
-    def _transfer_many_t(self, src, dsts, nbytes, rng):
-        k = len(dsts)
-        self.n_path_queries += k
-        if k == 0:
-            return []
-        self._touch_source(src)
-        t = self._tables_ready()
-        si = t.idx.get(src)
-        out: list[tuple[Optional[float], bool]] = []
-        if si is None or not t.live_node[si]:
-            return [(None, True)] * k
-        di = np.fromiter((t.idx.get(d, -1) for d in dsts),
-                         dtype=np.int64, count=k)
-        known = di >= 0
-        ok = known.copy()
-        ok[known] &= t.live_node[di[known]]
-        ok[known] &= np.isfinite(t.D[si, di[known]])
-        lat_row, bneck_row = t.LAT[si], t.BNECK[si]
-        keep_row = t.keep_row(self, si)
-        dj = np.where(ok, di, 0)
-        extra = None
-        if self.slow_extra_s:
-            g = self.slow_extra_s.get
-            e_src = g(src, 0.0)
-            extra = np.fromiter((e_src + g(d, 0.0) for d in dsts),
-                                dtype=np.float64, count=k)
-        delays = netcalc.delay_many(lat_row[dj], bneck_row[dj],
-                                    nbytes, extra)
-        keeps = keep_row[dj]
-        for i, d in enumerate(dsts):
-            if not ok[i]:
-                out.append((None, True))
-            elif di[i] == si:
-                out.append((0.0, False))
-            else:
-                lost = bool(rng and rng.random() > float(keeps[i]))
-                out.append((float(delays[i]), lost))
-        return out
+        tr = self._transfer_t
+        if self.profiler is None:
+            return [tr(src, d, nbytes, rng) for d in dsts]
+        return self._accounted(
+            lambda: [tr(src, d, nbytes, rng) for d in dsts])
 
     def path_latency_s(self, src: str, dst: str) -> Optional[float]:
         """Propagation latency of the current route (no serialization).

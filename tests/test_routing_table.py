@@ -25,6 +25,7 @@ from _hyp import given, settings, strategies as st
 
 from repro.core import Engine
 from repro.core.netem import LinkCfg, Network
+from repro.core.telemetry import Profiler
 from repro.sweep import topologies
 from repro.sweep.scenarios import build_scenario
 
@@ -105,6 +106,56 @@ def test_transfer_many_matches_per_destination_transfers():
     assert table.n_path_queries == legacy.n_path_queries
     assert table.n_graph_builds == legacy.n_graph_builds
     assert table.transfer_many("h0", [], 1, r1) == []
+
+
+def _fan_out_pair(seed):
+    """Two copies of one table-mode network: a slow source h0, a slow
+    destination h2 and a down host h5."""
+    nets = (random_net(seed, 12, seed % 2 == 1),
+            random_net(seed, 12, seed % 2 == 1))
+    for net in nets:
+        net.set_host_slow("h0", 0.125)
+        net.set_host_slow("h2", 0.25)
+        net.set_host_up("h5", False)
+    return nets
+
+
+@pytest.mark.parametrize("seed,k", [(0, 0), (1, 1), (2, 2), (3, 2),
+                                    (4, 5), (5, 5), (6, 32), (7, 32)])
+def test_transfer_many_matches_table_transfer_loop(seed, k):
+    """The table-mode fan-out answers each destination as ``transfer``
+    does on a copy of the same network: results, RNG draws and
+    counters, across a keep rebuild that drops the row cache mid-run
+    and from a down source."""
+    many, loop = _fan_out_pair(seed)
+    pool = ["h2", "h5", "nope", "h0"] + [f"h{i}" for i in range(1, 12)]
+    dsts = [pool[(seed + i) % len(pool)] for i in range(k)]
+    r1, r2 = random.Random(seed), random.Random(seed)
+
+    def fan_out(src, nbytes):
+        got = many.transfer_many(src, dsts, nbytes, r1)
+        want = [loop.transfer(src, d, nbytes, r2) for d in dsts]
+        assert got == want
+        assert r1.getstate() == r2.getstate()
+        assert many.n_path_queries == loop.n_path_queries
+        assert many.n_graph_builds == loop.n_graph_builds
+
+    fan_out("h0", 4096)
+    fan_out("h3", 100)
+    for net in (many, loop):          # every route out of h0 now drops
+        for a, b in sorted(many.g.edges("h0")):
+            net.set_link_loss(a, b, 100.0)
+    fan_out("h0", 4096)
+    fan_out("h5", 100)                # the down source
+    assert len(many.transfer_many("h5", dsts, 1, r1)) == k
+
+
+def test_transfer_many_is_accounted_under_netem_path():
+    net = random_net(1, 6, False)
+    net.profiler = Profiler()
+    net.transfer_many("h0", ["h1", "h2"], 512, random.Random(0))
+    assert net.profiler.wall["netem_path"] > 0.0
+    assert net.n_path_queries == 2
 
 
 @given(seed=st.integers(0, 10**6), n=st.integers(2, 9))
