@@ -6,7 +6,7 @@ producers, every remaining host a consumer, plus a mid-run broker
 partition (elections + ISR churn exercise the controller loop and the
 routing-table invalidation path).
 
-Three claims, all recorded in ``BENCH_sweep_scale.json``:
+Three claims, all recorded in ``BENCH_sweep_scale.json``, and one pin:
 
 1. **Scale** — scenarios at 100/200/400/1000 nodes complete in
    (multiples of) real time, with a **per-phase timing breakdown** so
@@ -58,20 +58,14 @@ Schema::
       "reach_cache_compare": {n_hosts, horizon_sim_s,
                               events_uncached, events_cached,
                               computes_uncached, computes_cached,
-                              probe_reduction, events_equal},
-      "fetch_mode_compare": {n_hosts, horizon_sim_s,
-                             events_legacy, events_fused,
-                             records_delivered, event_reduction,
-                             fingerprint_legacy, fingerprint_fused,
-                             fingerprints_equal}
+                              probe_reduction, events_equal}
     }
 
-4. **Fused fetch cohorts** (PR 9) — ``fetch_mode="fused"`` (the
-   default) coalesces same-tick wakeup/deliver events into cohort
-   events.  The before/after pair runs one identical chaotic
-   multi-partition scenario under both modes, **asserts bit-identity**
-   of every metric outside the event-loop counters, and gates the
-   deterministic event-count reduction (``MIN_FETCH_EVENT_REDUCTION``).
+4. **Delivery cohorts** — the broker coalesces same-tick wakeup and
+   deliver events into cohort events.  One chaotic multi-partition
+   scenario at the compare size must reproduce the exact
+   ``engine_events`` and deterministic-metrics fingerprint recorded at
+   commit 1e45a58 (``COHORT_PINS``).
 """
 from __future__ import annotations
 
@@ -97,10 +91,14 @@ from benchmarks.common import emit  # noqa: E402
 # reductions to avoid flaking, and both ratios are deterministic counts
 MIN_PROBE_REDUCTION = 5.0
 MIN_ROUTE_SOLVE_REDUCTION = 5.0
-# fused fetch cohorts merge same-tick wakeup/deliver events; the
-# reduction is an exact event-count ratio (never wall clock), gated
-# below the observed 1.27x (60-node smoke) / 1.39x (200-node) compare
-MIN_FETCH_EVENT_REDUCTION = 1.2
+# the cohort scenario per (n_hosts, horizon): exact engine events and
+# metrics_fingerprint, recorded at commit 1e45a58
+COHORT_PINS = {
+    (60, 4.0): (2116, "c52c1974afa15a646ce0ba4c39a854643a039ce2afe137398f"
+                      "572c472e3f7aa5"),
+    (200, 6.0): (10252, "9c881ae4af94796afd56bfa43c614700c30a20bf3ba3a6d3"
+                        "58e95cc065fe1fc1"),
+}
 
 
 def scale_base(horizon: float) -> dict:
@@ -120,18 +118,11 @@ def scale_base(horizon: float) -> dict:
 # route modes *by design* (it is the work the tables amortize away)
 _NONDET_KEYS = frozenset(TIMING_KEYS) | {"route_solves", "phases"}
 
-# the event-loop counters that fused cohort delivery merges *by
-# design*; everything else must stay bit-identical across fetch modes
-_EVENT_KEYS = frozenset({"engine_events", "events_scheduled",
-                         "events_cancelled", "profile_counts",
-                         "profile_wall"})
 
-
-def metrics_fingerprint(m: dict, exclude: frozenset = _NONDET_KEYS
-                        ) -> str:
+def metrics_fingerprint(m: dict) -> str:
     """Hash over the deterministic metrics of one engine run (the
     single-scenario analogue of ``SweepResults.fingerprint``)."""
-    det = {k: v for k, v in m.items() if k not in exclude}
+    det = {k: v for k, v in m.items() if k not in _NONDET_KEYS}
     blob = json.dumps(det, sort_keys=True, default=repr)
     return hashlib.sha256(blob.encode()).hexdigest()
 
@@ -215,42 +206,17 @@ def _compare_route_modes(n_hosts: int, horizon: float) -> dict:
     }
 
 
-def _compare_fetch_modes(n_hosts: int, horizon: float) -> dict:
-    """Identical chaotic multi-partition scenario under both fetch
-    modes: bit-identity of every non-event-loop metric asserted, the
-    deterministic event-count reduction gated (PR 9)."""
-    runs = {}
-    for mode in ("legacy", "fused"):
-        m = run_sized(n_hosts, horizon,
-                      extra={"fetch_mode": mode, "chaos": 2,
-                             "partitions": 4})
-        m.pop("phases")
-        runs[mode] = m
-    before, after = runs["legacy"], runs["fused"]
-    excl = _NONDET_KEYS | _EVENT_KEYS
-    fp_b = metrics_fingerprint(before, excl)
-    fp_a = metrics_fingerprint(after, excl)
-    assert fp_b == fp_a, \
-        "fetch modes disagree on deterministic metrics:\n" + "\n".join(
-            f"  {k}: {before[k]!r} != {after[k]!r}"
-            for k in sorted(before)
-            if k not in excl and before[k] != after[k])
-    reduction = before["engine_events"] / max(1, after["engine_events"])
-    assert reduction >= MIN_FETCH_EVENT_REDUCTION, \
-        f"fused fetch regressed: {reduction:.2f}x < " \
-        f"{MIN_FETCH_EVENT_REDUCTION}x event reduction " \
-        f"({before['engine_events']} -> {after['engine_events']} events)"
-    return {
-        "n_hosts": n_hosts,
-        "horizon_sim_s": horizon,
-        "events_legacy": before["engine_events"],
-        "events_fused": after["engine_events"],
-        "records_delivered": after["records_delivered"],
-        "event_reduction": reduction,
-        "fingerprint_legacy": fp_b,
-        "fingerprint_fused": fp_a,
-        "fingerprints_equal": True,
-    }
+def _check_cohort_pin(n_hosts: int, horizon: float) -> dict:
+    """The chaotic multi-partition scenario against its recorded event
+    count and fingerprint."""
+    m = run_sized(n_hosts, horizon, extra={"chaos": 2, "partitions": 4})
+    m.pop("phases")
+    got = (m["engine_events"], metrics_fingerprint(m))
+    assert got == COHORT_PINS[(n_hosts, horizon)], \
+        f"cohort scenario ({n_hosts} hosts, {horizon} s) changed: " \
+        f"{got} != recorded {COHORT_PINS[(n_hosts, horizon)]}"
+    return {"engine_events": got[0],
+            "records_delivered": m["records_delivered"]}
 
 
 def run(*, smoke: bool = False, full: bool = False, profile: bool = False,
@@ -301,13 +267,11 @@ def run(*, smoke: bool = False, full: bool = False, profile: bool = False,
          f"path_queries={rm['path_queries']};"
          f"fingerprints_equal={rm['fingerprints_equal']}")
 
-    # fused vs legacy fetch on one identical chaotic scenario (PR 9)
-    results["fetch_mode_compare"] = fm = _compare_fetch_modes(cmp_n, cmp_h)
-    emit("sweep_scale/fetch_mode", 0.0,
-         f"event_reduction={fm['event_reduction']:.2f}x;"
-         f"events={fm['events_legacy']}->{fm['events_fused']};"
-         f"delivered={fm['records_delivered']};"
-         f"fingerprints_equal={fm['fingerprints_equal']}")
+    # the delivery-cohort scenario against its recorded pin
+    cp = _check_cohort_pin(cmp_n, cmp_h)
+    emit("sweep_scale/cohort_pin", 0.0,
+         f"events={cp['engine_events']};"
+         f"delivered={cp['records_delivered']}")
 
     # before/after reachability caching on one identical scenario
     pair_sweep = SweepSpec(

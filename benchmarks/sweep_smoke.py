@@ -34,24 +34,22 @@ delivery modes and shed policies, gating:
   modes for otherwise-identical params — the chaos schedule and
   producer-side protocol randomness must not see the consumer loop.
 
-``--fetch`` runs the PR 9 fused-cohort gates on a ``fetch_mode`` axis
-over the chaotic bounded-queue grid:
+``--fetch`` runs the delivery-cohort gates over the chaotic
+bounded-queue grid with four partitions per topic, in both delivery
+modes:
 
-- every metric outside the event-loop counters (``engine_events``,
-  ``events_scheduled``, ``events_cancelled``) bit-identical between
-  ``fetch_mode="fused"`` (the default) and ``"legacy"`` on every other
-  grid point — shed/pause counters, chaos faults and RNG-fed latencies
-  included;
-- per-message sink digests (which consumer got which record, at which
-  offset order) identical across the modes on a direct engine pair;
-- the fused event-count *reduction* on the wakeup rows gated as an
-  exact deterministic ratio (never wall clock).
+- each row's exact ``engine_events`` and ``events_scheduled`` equal
+  the counts recorded at commit 1e45a58 (``FETCH_PINS``), so a change
+  that merges or splits events shows here, never through wall clock;
+- per-message sink digests (which consumer got which record, when) on
+  a direct engine run equal the recorded ones;
+- the grid sheds records (the overload is real).
 
 ``--telemetry`` runs the observability gates (the CI ``obs-smoke`` job):
 
 - telemetry artifacts (series digests, stage-span histograms, flight
-  and profiler call counts) bit-identical across warm-pool processes,
-  the heap/calendar scheduler axis and columnar on/off;
+  and profiler call counts) bit-identical across warm-pool processes
+  and columnar on/off;
 - telemetry-on adds only its own sampler events and < 5% extra engine
   events on the smoke scenario, perturbing no other metric;
 - telemetry off (param absent or 0) is byte-for-byte inert;
@@ -141,25 +139,25 @@ def chaos_main() -> None:
 
 FETCH_CACHE = ".ci_sweep_fetch"
 
-# PR 9: the chaotic bounded-queue base with multiple partitions per
-# topic so deliver cohorts actually form, crossed with the fetch modes
+# the chaotic bounded-queue base with multiple partitions per topic so
+# deliver cohorts actually form
 fetch_sweep = SweepSpec(
     name="ci_fetch_smoke",
-    axes={"delivery": ["poll", "wakeup"],
-          "fetch_mode": ["fused", "legacy"]},
+    axes={"delivery": ["poll", "wakeup"]},
     base={**chaos_sweep.base, "partitions": 4,
           "shed_policy": "drop_oldest"})
 
-# only the event-loop counters may differ between fetch modes
-FETCH_EVENT_KEYS = ("engine_events", "events_scheduled",
-                    "events_cancelled", "wall_s")
-MIN_SMOKE_FETCH_REDUCTION = 1.05
+# per delivery mode: (engine_events, events_scheduled, sink digest),
+# recorded at commit 1e45a58
+FETCH_PINS = {
+    "poll": (3223, 3237, "ee1e37949c7f987f"),
+    "wakeup": (4257, 4268, "05235c0bec7f8cf1"),
+}
 
 
 def fetch_main() -> None:
-    """The --fetch gates: fused vs legacy bit-identity on everything
-    but the event-loop counters, sink-digest identity, and the exact
-    event-reduction ratio on the wakeup rows."""
+    """The --fetch gates: exact event counts and sink digests against
+    the recorded ones, and a grid that sheds."""
     import hashlib
 
     from repro.core.engine import Engine
@@ -168,51 +166,30 @@ def fetch_main() -> None:
     shutil.rmtree(FETCH_CACHE, ignore_errors=True)
     a = run_sweep(fetch_sweep, workers=2, cache_dir=FETCH_CACHE,
                   progress=print)
-    assert len(a) == 4 and a.n_cached == 0
-    rows = {(r["params"]["delivery"], r["params"]["fetch_mode"]):
-            r["metrics"] for r in a.rows}
-    for delivery in ("poll", "wakeup"):
-        fused = rows[(delivery, "fused")]
-        legacy = rows[(delivery, "legacy")]
-        diffs = [k for k in legacy
-                 if k not in FETCH_EVENT_KEYS and fused[k] != legacy[k]]
-        assert not diffs, \
-            f"fetch modes disagree on {delivery}: " + ", ".join(
-                f"{k}: {fused[k]!r} != {legacy[k]!r}" for k in diffs)
-        assert fused["engine_events"] <= legacy["engine_events"], \
-            f"fused scheduled MORE events on {delivery}"
-        assert fused["records_shed"] > 0, \
-            f"the overload grid must exercise shedding ({delivery})"
-    reduction = (rows[("wakeup", "legacy")]["engine_events"]
-                 / rows[("wakeup", "fused")]["engine_events"])
-    assert reduction >= MIN_SMOKE_FETCH_REDUCTION, \
-        f"fused wakeup event reduction {reduction:.2f}x < " \
-        f"{MIN_SMOKE_FETCH_REDUCTION}x"
-
-    # sink-digest identity on a direct engine pair: the per-message
-    # delivery map (which consumers received each record, when) hashes
-    # identically — record streams, not just aggregates, must agree
-    digests = {}
-    for mode in ("fused", "legacy"):
-        p = {**fetch_sweep.base, "delivery": "wakeup",
-             "fetch_mode": mode}
+    assert len(a) == 2 and a.n_cached == 0
+    for r in a.rows:
+        delivery, m = r["params"]["delivery"], r["metrics"]
+        p = {**fetch_sweep.base, "delivery": delivery}
         eng = Engine(build_scenario(p), seed=int(p["seed"]))
         mon = eng.run(until=float(p["horizon"]))
-        blob = repr([(mid, sorted(m.deliveries.items()))
-                     for mid, m in sorted(mon.msgs.items())])
-        digests[mode] = hashlib.sha256(blob.encode()).hexdigest()[:16]
-    assert digests["fused"] == digests["legacy"], \
-        f"sink digests diverged across fetch modes: {digests}"
+        blob = repr([(mid, sorted(msg.deliveries.items()))
+                     for mid, msg in sorted(mon.msgs.items())])
+        got = (m["engine_events"], m["events_scheduled"],
+               hashlib.sha256(blob.encode()).hexdigest()[:16])
+        assert got == FETCH_PINS[delivery], \
+            f"{delivery}: (events, scheduled, sink) {got} != " \
+            f"recorded {FETCH_PINS[delivery]}"
+        assert m["records_shed"] > 0, \
+            f"the overload grid must exercise shedding ({delivery})"
     print(a.table())
-    print(f"fetch smoke ok | wakeup event reduction: {reduction:.2f}x "
-          f"| sink digest: {digests['fused']} "
-          f"| shed(wakeup/fused): "
-          f"{rows[('wakeup', 'fused')]['records_shed']}")
+    print("fetch smoke ok |", ", ".join(
+        f"{d}: {v[0]} events, sink {v[2]}"
+        for d, v in sorted(FETCH_PINS.items())))
 
 
 tel_sweep = SweepSpec(
     name="ci_tel_smoke",
-    axes={"scheduler": ["calendar", "heap"], "columnar": [0, 1]},
+    axes={"columnar": [0, 1]},
     base={**chaos_sweep.base, "consumer_groups": 1,
           "telemetry": 0.5, "profile": 1, "lineage_k": 2})
 
@@ -229,16 +206,15 @@ def telemetry_main() -> None:
     os.makedirs(TEL_CACHE)
     a = run_sweep(tel_sweep, workers=2, cache_dir=TEL_CACHE,
                   progress=print)
-    assert len(a) == 4 and a.n_cached == 0
-    rows = {(r["params"]["scheduler"], r["params"]["columnar"]):
-            r["metrics"] for r in a.rows}
-    ref = rows[("calendar", 1)]
+    assert len(a) == 2 and a.n_cached == 0
+    rows = {r["params"]["columnar"]: r["metrics"] for r in a.rows}
+    ref = rows[1]
     for key, m in sorted(rows.items()):
         for k in ("telemetry_digest", "stage_digest", "telemetry_samples",
                   "telemetry_series", "stage_spans", "flight_events",
                   "lineage_records", "profile_counts"):
             assert m[k] == ref[k], \
-                f"{k} differs across scheduler/columnar axis {key}"
+                f"{k} differs across the columnar axis ({key})"
 
     def _run(params):
         eng = Engine(build_scenario(params), seed=int(params["seed"]))

@@ -741,13 +741,6 @@ class Cluster:
         # `now - held < max_wait` loses to rounding about a third of
         # the time and would re-park the waiter with no timer left.
         self._hold_deadline: dict[tuple[str, str], float] = {}
-        # fetch_mode="fused" (default): one cohort deliver event per
-        # (subscriber, fetch cycle, landing time) and one cohort wakeup
-        # event per _notify fan-out, instead of one event per partition
-        # / per waiter.  Execution order is provably identical (see
-        # Engine.schedule_cohort); only the event-loop counters differ.
-        self._fused = str(getattr(engine, "fetch_mode", "fused")) \
-            == "fused"
         # assigned-partitions memo: (consumer, topic) -> (generation,
         # sorted partition tuple); invalidated by the generation bump in
         # _assign (rebalance), never recomputed on the fetch hot path
@@ -900,24 +893,16 @@ class Cluster:
     def _notify(self, topic: str) -> None:
         """Wake every parked subscriber of ``topic``.
 
-        Legacy mode schedules one zero-delay event per waiter; fused
-        mode drains the fan-out as one same-tick cohort event running
-        the same wakeups in the same order (Engine.schedule_cohort —
-        the k events would have held consecutive sequence numbers, so
-        nothing could pop between them and anything the wakeups
-        schedule keeps its relative order either way)."""
+        The fan-out is one zero-delay cohort event that runs the
+        wakeups in registration order (:meth:`Engine.schedule_cohort`)."""
         waiting = self._waiters.get(topic)
         if not waiting:
             return
         eng = self.engine
         consumers = list(waiting.values())
         waiting.clear()
-        if self._fused:
-            eng.schedule_cohort(0.0, [c.on_wakeup for c in consumers],
-                                eng, topic)
-        else:
-            for c in consumers:
-                eng.schedule(0.0, lambda c=c: c.on_wakeup(eng, topic))
+        eng.schedule_cohort(0.0, [c.on_wakeup for c in consumers],
+                            eng, topic)
 
     # ------------------------------------------------------------------
     # Client metadata (stale caches refreshed via reachable brokers)
@@ -1238,19 +1223,17 @@ class Cluster:
         return self._fetch(consumer, topic)
 
     def _fetch(self, consumer, topic: str) -> str:
-        """One fused fetch cycle over every owned partition.
+        """One fetch cycle over every owned partition.
 
         The per-partition work is a single flat pass with every
         per-fetch-invariant lookup (group, cfg, metadata dicts, budget
         hook, telemetry) hoisted out of the loop; byte accounting reads
-        the ``cum_list`` prefix-sum mirrors.  RNG draw order — one
-        control RTT then one data transfer per partition, in assignment
-        order — is exactly the legacy sequence, so loss/fault behavior
-        is untouched.  ``fetch_mode`` only controls how the responses
-        are *scheduled*: legacy posts one deliver event per partition,
-        fused groups consecutive equal-landing-time responses into
-        cohort events (``t_land`` is non-decreasing across the loop, so
-        equal values are always adjacent — see kernels/cohort.py).
+        the ``cum_list`` prefix-sum mirrors.  Each partition draws one
+        control RTT then one data transfer from the subscriber's RNG, in
+        the cycle's walk order.  The responses are scheduled as one
+        deliver event per landing time (:meth:`_deliver_cohort`):
+        ``t_land`` is non-decreasing across the loop, so equal values
+        are always adjacent (see kernels/cohort.py).
 
         Unbounded subscribers walk their assignment in order and take up
         to ``fetch_bytes`` a partition.  A bounded subscriber
@@ -1364,8 +1347,7 @@ class Cluster:
             n_capped = 0
             if prof is not None:
                 prof.add("backpressure", time.perf_counter() - t0)
-        fused = self._fused
-        pend: Optional[list] = [] if fused else None
+        pend: list = []                 # (t_land, batch, mids, pts)
         tx_hosts: list = []             # per-response leader/bytes for
         tx_bytes: list = []             # one batched broker_tx tally
         any_more = any_blocked = any_delivered = False
@@ -1463,13 +1445,7 @@ class Cluster:
             # is non-decreasing across this loop.
             t_land = max(now + rtt + delay, inflight.get(ikey, 0.0))
             inflight[ikey] = t_land
-            if fused:
-                pend.append((t_land, batch, mids, pts))
-            else:
-                eng.schedule(
-                    t_land - now,
-                    lambda b=batch, m=mids, p=pts:
-                        self._deliver_one(consumer, topic, b, m, p))
+            pend.append((t_land, batch, mids, pts))
             if prof is not None:
                 prof.add("fetch_take", time.perf_counter() - t1)
             if off + n < hw:
@@ -1484,10 +1460,8 @@ class Cluster:
                     tx_hosts, tx_bytes).items():
                 mon.broker_tx(h, nb)
         if pend:
-            # one cohort deliver event per distinct landing time; the
-            # per-partition events it replaces would have carried
-            # consecutive sequence numbers, so executing the views in
-            # order inside one event preserves the pop order exactly
+            # one cohort deliver event per distinct landing time; it
+            # runs the views in fetch order
             for lo, hi in cohort_kernels.group_spans(
                     [p[0] for p in pend]):
                 group = pend[lo:hi]
@@ -1553,32 +1527,17 @@ class Cluster:
             eng = self.engine
             eng.schedule(0.0, lambda: c.on_wakeup(eng, topic))
 
-    def _deliver_one(self, consumer, topic: str, batch, mids,
-                     pts) -> None:
-        """Legacy response landing: one deliver event per partition."""
-        eng = self.engine
-        prof = eng.profiler
-        t0 = time.perf_counter() if prof is not None else 0.0
-        now = eng.now
-        eng.monitor.delivered_many(mids, consumer.name, now)
-        tel = eng.telemetry
-        if tel is not None:
-            tel.span_many("deliver", topic, now - pts)
-            tel.lineage_mark(mids, "deliver", now)
-        consumer.on_records(eng, batch)
-        if prof is not None:
-            prof.add("deliver", time.perf_counter() - t0)
-
     def _deliver_cohort(self, consumer, topic: str, group) -> None:
-        """Fused response landing: one event for every response of one
-        fetch cycle that lands at the same instant.
+        """Response landing: one event for every response of one fetch
+        cycle that lands at the same instant.
 
-        The monitor/telemetry tallies run per view in legacy order —
+        The monitor/telemetry tallies run once per view, in fetch order:
         ``delivered_many`` and ``span_many`` accumulate float histograms
-        whose grouping must not change (the no-concatenation rule in the
-        ROADMAP cohort contract) — then the subscriber ingests the whole
-        cohort through ``on_records_cohort`` (per-view processing, with
-        per-cohort invariants hoisted; see core/subscription.py).
+        whose grouping the pinned fingerprints fix (the no-concatenation
+        rule in the ROADMAP cohort contract).  Then the subscriber
+        ingests the whole cohort through ``on_records_cohort`` (per-view
+        processing, with per-cohort invariants hoisted; see
+        core/subscription.py).
         """
         eng = self.engine
         prof = eng.profiler
@@ -1597,8 +1556,8 @@ class Cluster:
         else:
             consumer.on_records_cohort(eng, [g[1] for g in group])
         if prof is not None:
-            # `deliver` counts stay per-view (cross-mode comparable);
-            # the cohort event's wall and count land in deliver_cohort
+            # `deliver` counts views; the cohort event's wall and
+            # count land in deliver_cohort
             prof.add("deliver", 0.0, n=len(group))
             prof.add("deliver_cohort", time.perf_counter() - t0)
 

@@ -1,4 +1,4 @@
-"""Event-queue backends for the engine: adaptive calendar queue + heap.
+"""The engine's event queue: an adaptive calendar queue.
 
 The engine's scheduled-event set is dominated by *near-future* timers:
 linger flushes, fetch holds, poll retries, network transfer landings and
@@ -31,8 +31,8 @@ plus an overflow heap beyond the wheel horizon:
   re-bucketed when the wheel rotates into their window; an empty wheel
   fast-forwards whole windows at O(1) per window.
 
-**Determinism contract** — the pop sequence is *bit-identical* to the
-global heap's, in every mode and across promotion: entries are
+**Determinism contract** — the pop sequence is *bit-identical* to a
+binary heap's, in every mode and across promotion: entries are
 ``(t, seq, handle)`` tuples under the same ``(t, seq)`` total order,
 buckets partition the time axis (equal times always share a bucket),
 and bucket classification is monotone in ``t``, so cross-bucket order
@@ -48,25 +48,6 @@ handle's entry is left in place and skipped by the engine at pop time.
 from __future__ import annotations
 
 from heapq import heapify, heappop, heappush
-
-
-class HeapQueue:
-    """The legacy global binary heap (kept for parity checks)."""
-
-    __slots__ = ("_q",)
-
-    def __init__(self) -> None:
-        self._q: list = []
-
-    def push(self, t: float, seq: int, h) -> None:
-        heappush(self._q, (t, seq, h))
-
-    def pop(self):
-        q = self._q
-        return heappop(q) if q else None
-
-    def __len__(self) -> int:
-        return len(self._q)
 
 
 class CalendarQueue:
@@ -220,12 +201,3 @@ class CalendarQueue:
         self._cur = 0
         self._heaped = False
 
-
-def make_queue(kind: str):
-    """Queue factory: ``"calendar"`` (default hot path) or ``"heap"``."""
-    if kind == "calendar":
-        return CalendarQueue()
-    if kind == "heap":
-        return HeapQueue()
-    raise ValueError(f"unknown scheduler {kind!r} "
-                     "(expected 'calendar' or 'heap')")

@@ -1,6 +1,6 @@
 """Discrete-event emulation engine (the Mininet-role substrate).
 
-The engine advances a simulated clock over a heap of scheduled events.
+The engine advances a simulated clock over a queue of scheduled events.
 Components (producers, brokers, SPEs, consumers, stores) are runtime
 objects instantiated from the :class:`~repro.core.spec.PipelineSpec`; the
 network model (``netem``) provides message timing, the broker cluster
@@ -16,11 +16,15 @@ property for outputs.
 
 Hot-path design (large sweeps, 100+ emulated nodes):
 
-- Events live in a **calendar queue** (:mod:`repro.core.calqueue`):
-  near-future timers — the dominant pattern — cost O(1)/O(log bucket)
-  instead of O(log total).  Pop order is bit-identical to the legacy
-  global heap (``scheduler="heap"``), which stays available for parity
-  checks.
+- Events live in a **calendar queue** (:mod:`repro.core.calqueue`): a
+  binary heap while few events are outstanding, a bucketed timing wheel
+  once many are, where near-future timers — the dominant pattern —
+  cost O(1)/O(log bucket) instead of O(log total).  The pop order is
+  the ``(t, seq)`` order either way.
+- Same-tick fan-outs are **cohorts**: the broker wakes all parked
+  subscribers of a topic, and delivers the responses of one fetch cycle
+  that land at one instant, through one event each
+  (:meth:`Engine.schedule_cohort`).
 - :meth:`Engine.schedule` returns a cancellable :class:`EventHandle`;
   cancellation is *lazy* (the queue entry is skipped at pop time), so
   cancel is O(1) and no queue structure is ever re-sifted.
@@ -47,7 +51,7 @@ import zlib
 from typing import Callable, Optional
 
 from repro.core.broker import Cluster
-from repro.core.calqueue import make_queue
+from repro.core.calqueue import CalendarQueue
 from repro.core.monitor import Monitor
 from repro.core.state import MemoryStateBackend
 from repro.core.spec import (
@@ -97,8 +101,7 @@ class HostRuntime:
 
 class Engine:
     def __init__(self, spec: PipelineSpec, *, seed: int = 0,
-                 monitor: Optional[Monitor] = None,
-                 scheduler: Optional[str] = None) -> None:
+                 monitor: Optional[Monitor] = None) -> None:
         problems = spec.validate()
         if problems:
             raise ValueError("invalid pipeline spec:\n  " +
@@ -114,12 +117,6 @@ class Engine:
         # component changes cannot perturb each other's randomness.
         self._client_rngs: dict[str, random.Random] = {}
         self.delivery_mode = getattr(spec, "delivery", "wakeup")
-        # fetch_mode="fused" (default): the broker coalesces same-tick
-        # deliver/wakeup fan-outs into cohort events (one event, same
-        # execution order); "legacy" keeps one event per partition /
-        # per waiter for parity baselines.  Everything except the
-        # event-loop counters is bit-identical between the two.
-        self.fetch_mode = getattr(spec, "fetch_mode", "fused")
         # columnar delivery (the allocation-free hot path): fetch hands
         # subscribers zero-copy BatchViews; False materializes Record
         # lists at the fetch boundary (the pre-refactor behavior, kept
@@ -140,16 +137,12 @@ class Engine:
         # restore from it on recovery (see core/spe.py + core/state.py)
         self.state_backend = MemoryStateBackend()
         self.now = 0.0
-        # event queue: "calendar" (bucketed near-future timers, the hot
-        # path) or "heap" (legacy global heap).  Pop order is bit-
-        # identical between the two (see core/calqueue.py).
-        self.scheduler = scheduler or getattr(spec, "scheduler", "calendar")
-        self._q = make_queue(self.scheduler)
+        self._q = CalendarQueue()
         self._seq = 0
         self._stopped = False
         # event-loop statistics (benchmarks / regression tracking)
         self.n_events = 0               # events actually executed
-        self.n_scheduled = 0            # events pushed onto the heap
+        self.n_scheduled = 0            # events pushed onto the queue
         self.n_cancelled = 0            # events skipped via lazy deletion
         # chaos: number of concrete faults an (optional) chaos plan
         # expanded into at install time (faults_mod.install sets it)
@@ -236,7 +229,7 @@ class Engine:
         the pushes), so no other same-timestamp event could have popped
         between them, and anything the fns schedule keeps its sequence
         order relative to both the cohort and each other.  Used by the
-        broker's fused fetch/notify paths (``fetch_mode="fused"``).
+        broker's fetch and notify paths.
         """
         if len(fns) == 1:
             f0 = fns[0]

@@ -440,7 +440,7 @@ class TumblingWindow(_WindowBase):
         start (``float(math.floor(q)) * w == np.floor(q) * w`` — the
         same IEEE ops, so pane keys are bit-identical to ``_starts``).
         The arithmetic lives in ``kernels/cohort.py`` (the Pallas-ready
-        cohort seam, shared with the fused fetch path)."""
+        cohort seam, shared with the fetch path)."""
         n = len(cols)
         if n < 8:
             return _WindowBase.process_cols(self, cols, ctx)

@@ -160,13 +160,9 @@ class PipelineSpec:
     """The full emulation task description."""
 
     def __init__(self, *, mode: str = "zk",
-                 delivery: str = "wakeup", columnar: bool = True,
-                 scheduler: str = "calendar",
-                 fetch_mode: str = "fused") -> None:
+                 delivery: str = "wakeup", columnar: bool = True) -> None:
         assert mode in ("zk", "kraft"), mode
         assert delivery in ("wakeup", "poll"), delivery
-        assert scheduler in ("calendar", "heap"), scheduler
-        assert fetch_mode in ("fused", "legacy"), fetch_mode
         self.hosts: dict[str, HostSpec] = {}
         self.topics: dict[str, TopicCfg] = {}
         self.faults: list[FaultCfg] = []
@@ -188,16 +184,6 @@ class PipelineSpec:
         # materializes per-row Record lists (legacy allocation pattern,
         # kept for parity checks and the allocation-counter baseline)
         self.columnar = bool(columnar)
-        # event queue backend: "calendar" (bucketed, the hot path) or
-        # "heap" (legacy global heap) — pop order is bit-identical
-        self.scheduler = scheduler
-        # fetch_mode="fused" (default): one deliver event per
-        # (subscriber, fetch cycle, landing time) cohort and one wakeup
-        # event per _notify fan-out; "legacy" keeps one event per
-        # partition / per waiter.  Every metric except the event-loop
-        # counters is bit-identical between the two (see the ROADMAP
-        # cohort-delivery contract and tests/test_fused_fetch.py).
-        self.fetch_mode = fetch_mode
         self._comp_seq = 0
 
     # ------------------------------------------------------------------
@@ -206,9 +192,8 @@ class PipelineSpec:
 
     @classmethod
     def from_topology(cls, g: "nx.Graph", *, mode: str = "zk",
-                      delivery: str = "wakeup", columnar: bool = True,
-                      scheduler: str = "calendar",
-                      fetch_mode: str = "fused") -> "PipelineSpec":
+                      delivery: str = "wakeup",
+                      columnar: bool = True) -> "PipelineSpec":
         """Build a spec from a generated topology graph.
 
         ``g`` follows the ``repro.sweep.topologies`` contract: nodes carry
@@ -216,8 +201,7 @@ class PipelineSpec:
         ``cfg`` :class:`LinkCfg`.  Components and topics are added on top
         by the caller (or by ``repro.sweep.scenarios.build_scenario``).
         """
-        spec = cls(mode=mode, delivery=delivery, columnar=columnar,
-                   scheduler=scheduler, fetch_mode=fetch_mode)
+        spec = cls(mode=mode, delivery=delivery, columnar=columnar)
         for n, attrs in g.nodes(data=True):
             if attrs.get("kind", "host") == "switch":
                 spec.add_switch(n)
@@ -472,25 +456,21 @@ def _load_cfg(value: str, base_dir: str) -> dict:
 
 
 def from_graphml(path: str, *, mode: Optional[str] = None,
-                 delivery: Optional[str] = None,
-                 fetch_mode: Optional[str] = None) -> PipelineSpec:
+                 delivery: Optional[str] = None) -> PipelineSpec:
     """Parse a paper-style GraphML description (plus side YAML files).
 
     Table I parity: besides ``topicCfg``/``faultCfg``, graph-level
     attributes may select ``mode`` ("zk"/"kraft"), ``delivery``
-    ("wakeup"/"poll"), ``fetchMode`` ("fused"/"legacy") and a default
-    ``brokerCfg`` (YAML file or inline YAML) applied to every broker
-    node — node-level ``brokerCfg`` entries override the graph-level
-    defaults key-by-key.  Explicit keyword arguments take precedence
+    ("wakeup"/"poll") and a default ``brokerCfg`` (YAML file or inline
+    YAML) applied to every broker node — node-level ``brokerCfg``
+    entries override the graph-level defaults key-by-key.  Explicit keyword arguments take precedence
     over graph attributes.
     """
     g = nx.read_graphml(path)
     base = os.path.dirname(os.path.abspath(path))
     mode = mode or str(g.graph.get("mode", "zk"))
     delivery = delivery or str(g.graph.get("delivery", "wakeup"))
-    fetch_mode = fetch_mode or str(g.graph.get("fetchMode", "fused"))
-    spec = PipelineSpec(mode=mode, delivery=delivery,
-                        fetch_mode=fetch_mode)
+    spec = PipelineSpec(mode=mode, delivery=delivery)
     base_broker_cfg = (_load_cfg(g.graph["brokerCfg"], base)
                        if "brokerCfg" in g.graph else {})
 

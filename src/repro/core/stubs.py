@@ -246,12 +246,12 @@ class ConsumerBase(DeliveryLoop):
         self.start_delivery(eng, self.topics)
 
     def on_records(self, eng, records) -> None:
-        # fused deliver cohorts arrive through the DeliveryLoop default
+        # deliver cohorts arrive through the DeliveryLoop default
         # on_records_cohort (per-view calls in landing order): each view
         # must chain busy_until through its own _done event so the sink
         # spans fire at per-view completion times — merging views into
         # one execute_on would change the sink histograms and break the
-        # fused/legacy parity contract (ROADMAP cohort contract).
+        # pinned fingerprints (ROADMAP cohort contract).
         #
         # load shedding happens at admission (offsets already advanced,
         # so shed rows are consumed-but-dropped, never replayed); a

@@ -306,16 +306,15 @@ class DeliveryLoop:
         """Cluster callback: the topic may have data past our offset."""
         self._fetch_once(eng, topic)
 
-    # -- cohort ingest (fetch_mode="fused") ----------------------------
+    # -- cohort ingest --------------------------------------------------
 
     def on_records_cohort(self, eng, batches) -> None:
         """Ingest every view of one same-tick deliver cohort.
 
-        Default: per-view ``on_records`` in landing order — identical
-        to the per-partition deliver events it replaces.  Processing
+        Default: per-view ``on_records`` in landing order.  Processing
         MUST stay per-view: each view's float accounting (histogram
         inserts, watermark advances, busy-horizon chaining) has an
-        order the fused/legacy parity contract pins; only per-cohort
+        order the pinned fingerprints fix; only per-cohort
         *invariants* (attribute lookups, alive checks — anything no
         event can change mid-cohort) may be hoisted by overrides (see
         SPERuntime.on_records_cohort and the ROADMAP cohort contract).

@@ -259,12 +259,9 @@ class Profiler:
     (offset/byte bookkeeping + response, one count per partition that
     passed the control phase) replace the former whole-call ``fetch``
     bucket so the next bottleneck hunt sees which half dominates.
-    ``deliver`` counts one per delivered view in *both* fetch modes;
-    fused mode adds ``deliver_cohort`` (one count + the cohort event's
-    wall per landing).  All counts are deterministic; ``deliver``,
-    ``fetch_ctl`` and ``fetch_take`` are identical across
-    fused/legacy, ``deliver_cohort`` and ``scheduler_pops`` are the
-    intentional event-count deltas.
+    ``deliver`` counts one per delivered view and ``deliver_cohort`` one
+    per cohort deliver event (with that event's wall).  All counts are
+    deterministic.
 
     Produce-path buckets: ``produce`` (a producer's send, or a send made
     outside one: retry, queued batch, linger flush), ``append`` (the

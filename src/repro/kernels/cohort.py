@@ -1,9 +1,8 @@
 """Cohort-delivery arithmetic (numpy-first, Pallas-ready seam).
 
-The fused fetch path (``core/broker.py``, ``fetch_mode="fused"``)
-coalesces the per-partition deliver events of one fetch cycle into
-cohort events and answers the cross-view bookkeeping with vectorized
-integer/float passes.  The pure arithmetic lives here so (a) the broker
+The fetch path (``core/broker.py``) delivers the responses of one fetch
+cycle that land at the same instant as one cohort event and answers the
+cross-view bookkeeping with vectorized integer/float passes.  The pure arithmetic lives here so (a) the broker
 and the window operators share one bit-exactness argument and (b) a
 Pallas kernel can slot in behind the same signatures for offline
 batch-shape experiments (flat float64 arrays in, one array out, no
@@ -66,7 +65,8 @@ def pane_starts(event_times, size_s: float) -> np.ndarray:
 
     One ``floor`` pass computes every pane start; bit-identical to
     :func:`pane_start` per element, so pane dict keys match the scalar
-    per-record path exactly (asserted in ``tests/test_fused_fetch.py``).
+    per-record path exactly (asserted by the test
+    ``test_pane_starts_matches_scalar_pane_start``).
     """
     if os.environ.get("REPRO_COHORT_BACKEND", "numpy") == "jax":
         return _pane_starts_jax(event_times, size_s)
@@ -76,7 +76,7 @@ def pane_starts(event_times, size_s: float) -> np.ndarray:
 def group_spans(values) -> list:
     """Boundaries ``[(lo, hi), ...]`` of consecutive equal-value runs.
 
-    The fused fetch groups same-landing-time responses with this: the
+    The fetch cycle groups same-landing-time responses with this: the
     per-partition ``t_land`` sequence is non-decreasing (each value is
     maxed with the connection's previous in-flight horizon), so equal
     values always form consecutive runs and each run becomes one cohort

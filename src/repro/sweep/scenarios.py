@@ -29,8 +29,6 @@ delivery / mode             "wakeup"|"poll", "zk"|"kraft"
 columnar      zero-copy BatchView delivery (default True); False
               materializes per-row Records at fetch — the allocation
               baseline axis (behavior is bit-identical either way)
-scheduler     engine event queue: "calendar" (default) | "heap"
-              (legacy global heap; pop order bit-identical)
 broker_cfg    dict merged into every broker component (Table I brokerCfg)
 loss_pct      uniform extra loss applied to every link
 reach_cache   per-epoch reachability memoization toggle (default on;
@@ -39,10 +37,6 @@ route_mode    "table" (default — per-epoch vectorized routing tables)
               | "ondemand" (legacy per-source SSSP; the parity baseline
               — results are bit-identical, asserted in CI).
               reach_cache=0 always implies on-demand recomputation.
-fetch_mode    "fused" (default — one fused fetch cycle per poll, same-tick
-              deliveries coalesced into cohort events) | "legacy"
-              (per-partition deliver events; the parity baseline — all
-              metrics except event-loop counters bit-identical, CI-gated)
 windowed / window_s
               truthy ``windowed`` (or ``window_s > 0``) places one
               stream processor on the last host: topics[0] -> "agg",
@@ -99,9 +93,7 @@ def build_scenario(p: dict) -> PipelineSpec:
         **dict(p.get("topo", {})))
     spec = PipelineSpec.from_topology(
         g, mode=p.get("mode", "zk"), delivery=p.get("delivery", "wakeup"),
-        columnar=bool(p.get("columnar", True)),
-        scheduler=p.get("scheduler", "calendar"),
-        fetch_mode=str(p.get("fetch_mode", "fused")))
+        columnar=bool(p.get("columnar", True)))
     spec.network.reach_cache = bool(p.get("reach_cache", True))
     spec.network.route_mode = str(p.get("route_mode", "table"))
     if p.get("loss_pct"):

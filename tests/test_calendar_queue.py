@@ -1,20 +1,22 @@
 """Calendar-queue scheduler: exact heap-order parity + engine parity.
 
-The queue replaces the engine's global heap, so its contract is strict:
+The queue is the engine's only event queue, so its contract is strict:
 the pop sequence must be *bit-identical* to ``heapq`` under the same
 ``(t, seq)`` entries — in compact (heap) mode, on the bucketed wheel,
 and across the adaptive promotion between them.  The fuzz tests drive
 all three through a schedule-heavy workload shaped like the engine's
 (zero-delay wakeups, near-future timers, a far tail of delivery-timeout
 retries); the engine tests pin that a full simulation is event-stream
-identical under ``scheduler="heap"`` and ``scheduler="calendar"``.
+identical on the queue's heap phase and on its wheel phase.
 """
+import heapq
 import random
 
 import pytest
 
+from _pins import queue_phase
 from repro.core import Engine, PipelineSpec
-from repro.core.calqueue import CalendarQueue, HeapQueue, make_queue
+from repro.core.calqueue import CalendarQueue
 
 
 class _H:
@@ -22,6 +24,19 @@ class _H:
 
     def __init__(self):
         self.cancelled = False
+
+
+class _HeapRef:
+    """The reference order: one binary heap over ``(t, seq)``."""
+
+    def __init__(self):
+        self._q = []
+
+    def push(self, t, seq, h):
+        heapq.heappush(self._q, (t, seq, h))
+
+    def pop(self):
+        return heapq.heappop(self._q) if self._q else None
 
 
 def _drive(q, *, n=20_000, seed=0, preload=300, zero_frac=0.2,
@@ -55,7 +70,7 @@ def _drive(q, *, n=20_000, seed=0, preload=300, zero_frac=0.2,
 
 @pytest.mark.parametrize("seed", [0, 7, 1234])
 def test_pop_order_identical_to_heap_all_modes(seed):
-    ref = _drive(HeapQueue(), seed=seed)
+    ref = _drive(_HeapRef(), seed=seed)
     wheel = _drive(CalendarQueue(promote_n=0), seed=seed)      # wheel-only
     adaptive = _drive(CalendarQueue(), seed=seed)              # compact
     promoted = _drive(CalendarQueue(promote_n=50), seed=seed)  # promotes
@@ -79,18 +94,16 @@ def test_far_future_overflow_and_rotation():
     # entries far beyond the wheel horizon come back in order, across
     # several window rotations and an idle fast-forward gap
     q = CalendarQueue(bucket_s=0.01, n_buckets=16, promote_n=0)  # 0.16 s
-    ref = HeapQueue()
     rng = random.Random(3)
     h = _H()
+    pushed = []
     for seq in range(1, 500):
         t = rng.choice([rng.random() * 0.1,          # in-window
                         rng.random() * 5.0,          # a few windows out
                         1000.0 + rng.random()])      # idle gap jump
         q.push(t, seq, h)
-        ref.push(t, seq, h)
-    a = [q.pop()[:2] for _ in range(499)]
-    b = [ref.pop()[:2] for _ in range(499)]
-    assert a == b
+        pushed.append((t, seq))
+    assert [q.pop()[:2] for _ in range(499)] == sorted(pushed)
     assert q.pop() is None
 
 
@@ -107,20 +120,23 @@ def test_len_tracks_entries():
 
 
 def test_make_queue_kinds():
-    assert isinstance(make_queue("calendar"), CalendarQueue)
-    assert isinstance(make_queue("heap"), HeapQueue)
-    with pytest.raises(ValueError):
-        make_queue("fifo")
+    # one event queue: no option selects another
+    with pytest.raises(TypeError):
+        PipelineSpec(scheduler="heap")
+    with pytest.raises(TypeError):
+        PipelineSpec(fetch_mode="legacy")
+    with pytest.raises(TypeError):
+        Engine(_spe_spec(), seed=0, scheduler="heap")
 
 
 # ---------------------------------------------------------------------------
-# Engine-level parity: full simulations bit-identical across schedulers
+# Engine-level parity: full simulations bit-identical across queue phases
 # ---------------------------------------------------------------------------
 
 
-def _spe_spec(scheduler):
+def _spe_spec():
     docs = ["to be or not to be", "be the change", "stream all things"]
-    spec = PipelineSpec(delivery="wakeup", scheduler=scheduler)
+    spec = PipelineSpec(delivery="wakeup")
     spec.add_switch("s1")
     for h in ["b", "h1", "h2", "h3"]:
         spec.add_host(h).add_link(h, "s1", lat=1.0, bw=1000.0)
@@ -135,26 +151,26 @@ def _spe_spec(scheduler):
     return spec
 
 
-def test_engine_event_streams_identical_across_schedulers():
+def test_engine_event_streams_identical_across_schedulers(monkeypatch):
     runs = {}
-    for scheduler in ("heap", "calendar"):
-        eng = Engine(_spe_spec(scheduler), seed=0)
+    for phase in ("calendar", "wheel"):
+        queue_phase(monkeypatch, phase)
+        eng = Engine(_spe_spec(), seed=0)
         mon = eng.run(until=15.0)
         sink = [rt for rt in eng.runtimes
                 if rt.name.startswith("consumer")][0]
         m = eng.metrics()
         m.pop("wall_s")
-        runs[scheduler] = (m, list(mon.events), list(sink.payloads))
-    assert runs["heap"] == runs["calendar"]
-    assert runs["heap"][2], "sink must receive results"
+        runs[phase] = (m, list(mon.events), list(sink.payloads))
+    assert runs["wheel"] == runs["calendar"]
+    assert runs["wheel"][2], "sink must receive results"
 
 
-def test_engine_uses_calendar_by_default():
-    eng = Engine(_spe_spec("calendar"), seed=0)
+def test_engine_uses_calendar_by_default(monkeypatch):
+    eng = Engine(_spe_spec(), seed=0)
     assert isinstance(eng._q, CalendarQueue)
-    assert eng.scheduler == "calendar"
-    eng2 = Engine(_spe_spec("heap"), seed=0)
-    assert isinstance(eng2._q, HeapQueue)
-    # explicit Engine kwarg overrides the spec knob
-    eng3 = Engine(_spe_spec("heap"), seed=0, scheduler="calendar")
-    assert isinstance(eng3._q, CalendarQueue)
+    assert eng._q._heap is not None        # starts in its heap phase
+    queue_phase(monkeypatch, "wheel")
+    eng2 = Engine(_spe_spec(), seed=0)
+    assert isinstance(eng2._q, CalendarQueue)
+    assert eng2._q._heap is None           # on the wheel from the start

@@ -8,7 +8,7 @@ load shedding (ISSUE 6 acceptance):
   ``Engine`` construction instead of a mid-run netem ``KeyError``;
 - one (spec, seed) names an entire adversarial run bit-identically:
   the expanded schedule and every degradation counter reproduce across
-  processes-in-spirit (fresh engines), delivery modes and schedulers;
+  processes-in-spirit (fresh engines), delivery modes and queue phases;
 - bounded ingest queues hold their byte bound under overload: ``pause``
   throttles the fetch path (and resumes — no hung waiters), shed
   policies drop deterministically at admission with counted metrics;
@@ -17,6 +17,7 @@ load shedding (ISSUE 6 acceptance):
 """
 import pytest
 
+from _pins import queue_phase
 from repro.core import Engine, PipelineSpec
 from repro.core.faults import expand_chaos
 from repro.core.operators import shed_keep
@@ -162,9 +163,8 @@ def test_chaos_validation_fails_fast(chaos, needle):
 # ---------------------------------------------------------------------------
 
 
-def chaos_spec(delivery="wakeup", scheduler="calendar", seed_axis=0):
+def chaos_spec(delivery="wakeup", seed_axis=0):
     spec = star_spec(delivery=delivery)
-    spec.scheduler = scheduler
     spec.set_chaos(start=1.0, duration=6.0, flap_links=1 + seed_axis,
                    gray=1, slow=1, crashes=1, protect=("b", "p"))
     return spec
@@ -199,11 +199,13 @@ def fault_trace(mon):
 
 
 @pytest.mark.parametrize("axis", [
-    {"delivery": "poll"}, {"scheduler": "heap"}])
-def test_chaos_schedule_blind_to_delivery_mode_and_scheduler(axis):
+    {"delivery": "poll"}, {"phase": "wheel"}])
+def test_chaos_schedule_blind_to_delivery_mode_and_scheduler(axis,
+                                                            monkeypatch):
     ref_eng = Engine(chaos_spec(), seed=5)
     ref = ref_eng.run(until=10.0)
-    eng = Engine(chaos_spec(**axis), seed=5)
+    queue_phase(monkeypatch, axis.get("phase", "calendar"))
+    eng = Engine(chaos_spec(axis.get("delivery", "wakeup")), seed=5)
     mon = eng.run(until=10.0)
     assert fault_trace(mon) == fault_trace(ref)
     a, b = eng.metrics(), ref_eng.metrics()

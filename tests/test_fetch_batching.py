@@ -10,6 +10,7 @@ linger like producers do.  Pins:
 """
 import pytest
 
+from _pins import digest, events_digest, metrics_digest
 from repro.core import Engine, PipelineSpec
 
 HORIZON = 20.0
@@ -17,8 +18,8 @@ TOTAL = 80
 MSG = 512
 
 
-def spec_with(broker_cfg, delivery="wakeup", fetch_mode="fused"):
-    spec = PipelineSpec(delivery=delivery, fetch_mode=fetch_mode)
+def spec_with(broker_cfg, delivery="wakeup"):
+    spec = PipelineSpec(delivery=delivery)
     spec.add_switch("s1")
     for h in ["b", "p", "c"]:
         spec.add_host(h).add_link(h, "s1", lat=1.0, bw=1000.0)
@@ -83,32 +84,27 @@ def test_sub_min_bytes_tail_always_delivers(delivery, seed):
         f"held tail stranded: {sink.n_received}/{TOTAL} delivered"
 
 
+# (metrics, monitor event stream, sink series) digests, recorded at
+# commit 1e45a58 (tests/_pins.py)
+HOLD_PINS = {
+    "wakeup": ("59a2549d944468a6", "4f53cda18c2baa0c", "cc2fa78822d25e4d"),
+    "poll": ("c569e08a7c9afc8b", "4f53cda18c2baa0c", "3f7b6a272452485d"),
+}
+
+
 @pytest.mark.parametrize("delivery", ["wakeup", "poll"])
 def test_hold_and_expiry_stream_identical_across_fetch_modes(delivery):
-    # PR 9: `_avail_bytes` now reads the cum_list prefix-sum mirror and
-    # the hold/expiry decisions run inside the fused fetch cycle — the
-    # full monitor event stream (hold entries, expiry wakeups, delivery
-    # ordering) and every metric must match the legacy per-partition
-    # path exactly, including the event-loop counters: the hold branch
-    # schedules single expiry wakeups in both modes, and this pipeline
-    # has one partition and one subscriber, so no cohorts form
+    # `_avail_bytes` reads the cum_list prefix-sum mirror and the
+    # hold/expiry decisions run inside the fetch cycle: the full monitor
+    # event stream (hold entries, expiry wakeups, delivery ordering) and
+    # every metric, event-loop counters included, must match the
+    # recorded run; this pipeline has one partition and one subscriber,
+    # so no cohorts form
     cfg = {"fetch_min_bytes": 8 * MSG, "fetch_max_wait_s": 0.1}
-    runs = {}
-    for fm in ("fused", "legacy"):
-        eng = Engine(spec_with(cfg, delivery, fetch_mode=fm), seed=11)
-        mon = eng.run(until=HORIZON)
-        sink = [rt for rt in eng.runtimes
-                if rt.name.startswith("consumer")][0]
-        runs[fm] = (eng, mon, sink)
-    f_eng, f_mon, f_sink = runs["fused"]
-    l_eng, l_mon, l_sink = runs["legacy"]
-    fm_, lm_ = f_eng.metrics(), l_eng.metrics()
-    assert {k: v for k, v in fm_.items() if k != "wall_s"} == \
-        {k: v for k, v in lm_.items() if k != "wall_s"}
-    assert [(e["kind"], e["t"]) for e in f_mon.events] == \
-        [(e["kind"], e["t"]) for e in l_mon.events]
-    assert f_sink.series == l_sink.series
-    assert f_sink.n_received == TOTAL
+    eng, mon, sink = run(cfg, delivery)
+    assert (metrics_digest(eng.metrics()), events_digest(mon),
+            digest(sink.series)) == HOLD_PINS[delivery]
+    assert sink.n_received == TOTAL
 
 
 def test_lingering_wakeup_reduces_engine_events():

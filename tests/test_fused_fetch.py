@@ -1,62 +1,49 @@
-"""Fused fetch/delivery cohorts (PR 9): fused-vs-legacy parity.
+"""Delivery cohorts: pinned runs of the fetch schedule.
 
-``fetch_mode="fused"`` (the default) runs one fused fetch cycle per
-poll — hoisted lookups, cum_list prefix-sum accounting — and coalesces
-same-tick work into cohort events: one deliver event per (subscriber,
-fetch cycle, landing time) and one wakeup event per ``_notify`` fan-out.
-``fetch_mode="legacy"`` schedules one event per partition / per waiter,
-exactly as before the refactor.
+A fetch cycle posts one deliver event per (subscriber, fetch cycle,
+landing time) and ``_notify`` wakes every parked subscriber of a topic
+through one cohort event (``Engine.schedule_cohort``).  Each scenario
+below is held to values recorded from the same scenario at commit
+1e45a58 (``PINS``): engine and scheduled event counts, and digests of
+every metric but the wall clock, of the monitor's ``(kind, t)`` event
+stream and of each message's delivery map (see ``tests/_pins.py``).
+That commit also ran a per-partition, per-waiter schedule and held it
+equal to these runs on every metric outside the event-loop counters.
+The scenarios cover every hard configuration the broker supports:
+both delivery modes on both phases of the calendar queue, record mode,
+consumer groups mid-rebalance, bounded queues under each shed policy,
+and exactly-once recovery under chaos.
 
-The contract, asserted here across every hard configuration the broker
-supports: **all metrics except the event-loop counters are
-bit-identical** between the modes — delivery tallies, RNG-fed latencies
-at full float precision, degradation counters, rebalance/chaos event
-streams, sink payload sequences — and fused never schedules *more*
-events.  Cohort execution-order equivalence is argued in
-``Engine.schedule_cohort``; the per-view float-accumulation rules are
-the ROADMAP cohort-delivery contract.
-
-Also covers the PR 9 satellites: the memoized ``assigned_partitions``
-rebalance regression and the ``kernels/cohort.py`` helpers.
+Also covers the memoized ``assigned_partitions`` rebalance regression
+and the ``kernels/cohort.py`` helpers.
 """
 import numpy as np
 import pytest
 
+from _pins import (PHASES, deliveries_digest, events_digest, metrics_digest,
+                   queue_phase)
 from repro.core import Engine, PipelineSpec
 from repro.kernels import cohort
 from repro.sweep import SweepSpec, run_sweep
 from repro.sweep.scenarios import build_scenario
 
-# the only metrics allowed to differ between fetch modes (plus wall
-# clock): cohort coalescing merges events, nothing else
-EVENT_KEYS = ("engine_events", "events_scheduled", "events_cancelled")
-PROF_KEYS = ("profile_counts", "profile_wall")
+
+def pinned(m, mon):
+    """What a pinned run is compared by."""
+    return (m["engine_events"], m["events_scheduled"], metrics_digest(m),
+            events_digest(mon), deliveries_digest(mon))
 
 
-def run_scenario(p, fetch_mode, seed=0):
-    eng = Engine(build_scenario({**p, "fetch_mode": fetch_mode}),
-                 seed=seed)
+def run_scenario(p, seed=0):
+    eng = Engine(build_scenario(p), seed=seed)
     mon = eng.run(until=float(p["horizon"]))
     return eng, mon, eng.metrics()
 
 
-def strip(m):
-    skip = set(EVENT_KEYS) | set(PROF_KEYS) | {"wall_s"}
-    return {k: v for k, v in m.items() if k not in skip}
-
-
-def assert_parity(p, seed=0, fewer_events=False):
-    """Run both modes; assert bit-identical non-event metrics and
-    identical monitor event streams; return both (eng, mon, metrics)."""
-    fused = run_scenario(p, "fused", seed)
-    legacy = run_scenario(p, "legacy", seed)
-    assert strip(fused[2]) == strip(legacy[2])
-    assert [(e["kind"], e["t"]) for e in fused[1].events] == \
-        [(e["kind"], e["t"]) for e in legacy[1].events]
-    assert fused[2]["engine_events"] <= legacy[2]["engine_events"]
-    if fewer_events:
-        assert fused[2]["engine_events"] < legacy[2]["engine_events"]
-    return fused, legacy
+def run_pinned(name, p, seed=0):
+    eng, mon, m = run_scenario(p, seed)
+    assert pinned(m, mon) == PINS[name], name
+    return eng, mon, m
 
 
 # a scenario where cohorts actually form: multiple partitions per topic
@@ -69,39 +56,77 @@ BASE = {
     "horizon": 8.0, "seed": 0,
 }
 
+# slow consumers + tiny queues force the degradation machinery
+BOUNDED = {**BASE, "delivery": "wakeup", "rate_kbps": 256.0,
+           "queue_bytes": 2 << 10, "consumer_cost": 0.1,
+           "horizon": 10.0}
+
+# a checkpointed exactly-once SPE, seeded chaos (flaps, gray loss, slow
+# hosts, crash/heal) and an spe_down fault
+EXACTLY_ONCE = {**BASE, "delivery": "wakeup", "windowed": 1,
+                "window_s": 1.0, "time_mode": "event", "et_jitter_s": 0.2,
+                "checkpoint_interval": 2.0, "spe_semantics": "exactly_once",
+                "fault": "spe_down", "fault_at": 4.0, "fault_duration": 2.0,
+                "chaos": 1, "horizon": 12.0}
+
+# (engine_events, events_scheduled, metrics, event stream, delivery
+# maps), recorded at commit 1e45a58
+PINS = {
+    "wakeup": (3518, 3525, "81ee7251f093216d", "4f53cda18c2baa0c",
+               "25563ebbe2d7e661"),
+    "poll": (3189, 3199, "4ebcfcc6075dad5f", "4f53cda18c2baa0c",
+             "55770d7f6929972f"),
+    "multi_partition_poll": (8650, 8666, "fdafbd72bb70710d",
+                             "4f53cda18c2baa0c", "29e8f6114e3f81bc"),
+    "record_mode": (3518, 3525, "5689e20da5fc3c91", "4f53cda18c2baa0c",
+                    "25563ebbe2d7e661"),
+    "exactly_once_chaos": (5204, 5218, "936b4b01eae686a6", "d098d9bc6a5d3ed6",
+                           "b8647071abd28079"),
+    "bounded/pause": (8363, 8391, "94ec92c5080726d8", "4f53cda18c2baa0c",
+                      "e4de730af6d544e2"),
+    "bounded/drop_oldest": (7501, 7529, "04aebe5b9c43e876", "338b0b8a519721b1",
+                            "cc532b30be4e2fb6"),
+    "bounded/drop_newest": (7501, 7529, "04aebe5b9c43e876", "338b0b8a519721b1",
+                            "cc532b30be4e2fb6"),
+    "bounded/sample": (7501, 7529, "240bdfb622da26d1", "338b0b8a519721b1",
+                       "cc532b30be4e2fb6"),
+    "group/wakeup": (875, 876, "dea9dded3ed7cce1", "35c36f34aac96388",
+                     "f1a1d6c4e43d607b"),
+    "group/poll": (1298, 1301, "37efbfd2c90ace73", "35c36f34aac96388",
+                   "c51bff539b929858"),
+}
+
 
 # ---------------------------------------------------------------------------
-# Core parity grid: delivery x scheduler
+# Core grid: delivery x queue phase
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("scheduler", ["calendar", "heap"])
+@pytest.mark.parametrize("scheduler", PHASES)
 @pytest.mark.parametrize("delivery", ["wakeup", "poll"])
-def test_parity_across_delivery_and_scheduler(delivery, scheduler):
-    p = {**BASE, "delivery": delivery, "scheduler": scheduler}
-    # wakeup mode has multi-waiter notifies -> strictly fewer events
-    assert_parity(p, fewer_events=(delivery == "wakeup"))
+def test_parity_across_delivery_and_scheduler(delivery, scheduler,
+                                              monkeypatch):
+    queue_phase(monkeypatch, scheduler)
+    eng, _, _ = run_pinned(delivery, {**BASE, "delivery": delivery})
+    # the default queue never leaves its heap phase at this size
+    assert (eng._q._heap is None) == (scheduler == "wheel")
 
 
 def test_multi_partition_deliver_cohorts_shrink_poll_events():
-    # with 4 partitions per topic and zero-latency-equal landings rare,
-    # cohorts still form whenever several partitions land together; at
-    # minimum the fused run never schedules more events, and the record
-    # stream is identical
-    p = {**BASE, "delivery": "poll", "rate_kbps": 256.0}
-    (ef, _, mf), (el, _, ml) = assert_parity(p)
-    assert mf["records_delivered"] == ml["records_delivered"] > 0
+    # with 4 partitions per topic, cohorts form whenever several
+    # partitions land together
+    _, _, m = run_pinned("multi_partition_poll",
+                         {**BASE, "delivery": "poll", "rate_kbps": 256.0})
+    assert m["records_delivered"] > 0
 
 
 def test_record_mode_parity():
-    # columnar=0 materializes per-row Records at fetch; the fused cycle
-    # must keep the materialization count and payloads identical
-    p = {**BASE, "delivery": "wakeup", "columnar": 0}
-    (ef, _, mf), (el, _, ml) = assert_parity(p, fewer_events=True)
-    # materialization happens at fetch-take, delivery at landing: the
-    # counts differ only by records still in flight at the horizon
-    assert mf["record_objects_materialized"] == \
-        ml["record_objects_materialized"] >= mf["records_delivered"] > 0
+    # columnar=0 materializes per-row Records at fetch; materialization
+    # happens at fetch-take, delivery at landing: the counts differ only
+    # by records still in flight at the horizon
+    _, _, m = run_pinned("record_mode",
+                         {**BASE, "delivery": "wakeup", "columnar": 0})
+    assert m["record_objects_materialized"] >= m["records_delivered"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -109,8 +134,8 @@ def test_record_mode_parity():
 # ---------------------------------------------------------------------------
 
 
-def group_spec(fetch_mode, delivery="wakeup"):
-    spec = PipelineSpec(delivery=delivery, fetch_mode=fetch_mode)
+def group_spec(delivery="wakeup"):
+    spec = PipelineSpec(delivery=delivery)
     spec.add_switch("s1")
     spec.add_host("b1").add_link("b1", "s1", lat=1.0, bw=100.0)
     spec.add_broker("b1")
@@ -129,29 +154,21 @@ def group_spec(fetch_mode, delivery="wakeup"):
 @pytest.mark.parametrize("delivery", ["wakeup", "poll"])
 def test_group_rebalance_parity(delivery):
     # a member dies and recovers mid-run: partitions move at committed
-    # offsets through two rebalances.  The fused cycle reads partitions
+    # offsets through two rebalances.  The fetch cycle reads partitions
     # through the generation-checked memo, so the event stream
     # (rebalances included), per-message delivery maps and group lag
-    # must match legacy exactly
-    runs = {}
-    for fm in ("fused", "legacy"):
-        eng = Engine(group_spec(fm, delivery), seed=9)
-        mon = eng.run(until=60.0)
-        runs[fm] = (eng, mon)
-    ef, mf = runs["fused"]
-    el, ml = runs["legacy"]
-    assert strip(ef.metrics()) == strip(el.metrics())
-    assert [(e["kind"], e["t"]) for e in mf.events] == \
-        [(e["kind"], e["t"]) for e in ml.events]
-    assert ef.metrics()["group_rebalances"] >= 2
-    for mid, msg in mf.msgs.items():
-        assert msg.deliveries == ml.msgs[mid].deliveries
+    # must match the recorded run exactly
+    eng = Engine(group_spec(delivery), seed=9)
+    mon = eng.run(until=60.0)
+    m = eng.metrics()
+    assert pinned(m, mon) == PINS["group/" + delivery]
+    assert m["group_rebalances"] >= 2
 
 
 def test_assigned_partitions_memo_tracks_rebalance_generation():
-    # satellite 1 regression: the memo must serve the *current*
-    # assignment after every generation bump — never a stale tuple
-    eng = Engine(group_spec("fused"), seed=9)
+    # the memo must serve the *current* assignment after every
+    # generation bump — never a stale tuple
+    eng = Engine(group_spec(), seed=9)
     eng.run(until=60.0)
     cluster = eng.cluster
     consumers = list(cluster.subs["t"])
@@ -175,8 +192,7 @@ def test_assigned_partitions_memo_tracks_rebalance_generation():
 def test_solo_consumers_share_the_topic_partition_tuple():
     # implicit solo groups never rebalance: every call returns the
     # topic's precomputed partition tuple, no cache entry needed
-    p = {**BASE, "delivery": "poll"}
-    eng, _, _ = run_scenario(p, "fused")
+    eng, _, _ = run_scenario({**BASE, "delivery": "poll"})
     cluster = eng.cluster
     for topic, consumers in cluster.subs.items():
         for c in consumers:
@@ -193,20 +209,17 @@ def test_solo_consumers_share_the_topic_partition_tuple():
 @pytest.mark.parametrize("policy", ["pause", "drop_oldest",
                                     "drop_newest", "sample"])
 def test_bounded_queue_parity(policy):
-    # slow consumers + tiny queues force the degradation machinery;
-    # shed/pause decisions happen at admission (per view, in legacy
-    # order), so every degradation counter must match bit-for-bit
-    p = {**BASE, "delivery": "wakeup", "rate_kbps": 256.0,
-         "queue_bytes": 2 << 10, "consumer_cost": 0.1,
-         "shed_policy": policy, "horizon": 10.0}
-    (ef, _, mf), (el, _, ml) = assert_parity(p)
+    # shed/pause decisions happen at admission, per view in fetch order,
+    # so every degradation counter must match the recorded run
+    _, _, m = run_pinned("bounded/" + policy,
+                         {**BOUNDED, "shed_policy": policy})
     if policy == "pause":
-        assert mf["backpressure_pauses"] == ml["backpressure_pauses"] > 0
-        assert mf["records_shed"] == 0
+        assert m["backpressure_pauses"] > 0
+        assert m["records_shed"] == 0
     else:
-        assert mf["records_shed"] == ml["records_shed"] > 0
-        assert mf["bytes_shed"] == ml["bytes_shed"]
-    assert mf["queue_peak_bytes"] == ml["queue_peak_bytes"] > 0
+        assert m["records_shed"] > 0
+        assert m["bytes_shed"] > 0
+    assert m["queue_peak_bytes"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -215,21 +228,13 @@ def test_bounded_queue_parity(policy):
 
 
 def test_exactly_once_recovery_under_chaos_parity():
-    # a checkpointed exactly-once SPE, seeded chaos (flaps, gray loss,
-    # slow hosts, crash/heal) and an spe_down fault: recovery replays
-    # from the snapshot, and the replay/recovery accounting must be
-    # identical under fused fetch
-    p = {**BASE, "delivery": "wakeup", "windowed": 1, "window_s": 1.0,
-         "time_mode": "event", "et_jitter_s": 0.2,
-         "checkpoint_interval": 2.0, "spe_semantics": "exactly_once",
-         "fault": "spe_down", "fault_at": 4.0, "fault_duration": 2.0,
-         "chaos": 1, "horizon": 12.0}
-    (ef, _, mf), (el, _, ml) = assert_parity(p)
-    assert mf["spe_recoveries"] == ml["spe_recoveries"] >= 1
-    assert mf["checkpoint_count"] == ml["checkpoint_count"] > 0
-    assert mf["recovered_duplicates"] == ml["recovered_duplicates"]
-    assert mf["windows_fired"] == ml["windows_fired"] > 0
-    assert mf["chaos_faults"] == ml["chaos_faults"] >= 1
+    # recovery replays from the snapshot; the replay/recovery accounting
+    # must match the recorded run
+    _, _, m = run_pinned("exactly_once_chaos", EXACTLY_ONCE)
+    assert m["spe_recoveries"] >= 1
+    assert m["checkpoint_count"] > 0
+    assert m["windows_fired"] > 0
+    assert m["chaos_faults"] >= 1
 
 
 # ---------------------------------------------------------------------------
@@ -239,11 +244,11 @@ def test_exactly_once_recovery_under_chaos_parity():
 
 def test_fused_fingerprint_identical_across_worker_processes():
     # the sweep cache mixes rows from different spawned workers: the
-    # fused hot path must hash identically inline and in a worker pool
+    # cohort hot path must hash identically inline and in a worker pool
     grid = SweepSpec(
         name="fused_xproc",
         axes={"delivery": ["poll", "wakeup"]},
-        base={**BASE, "horizon": 5.0, "fetch_mode": "fused"})
+        base={**BASE, "horizon": 5.0})
     inline = run_sweep(grid, workers=1, cache_dir=None)
     pooled = run_sweep(grid, workers=2, cache_dir=None)
     assert inline.fingerprint() == pooled.fingerprint()

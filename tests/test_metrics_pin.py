@@ -21,12 +21,11 @@ PR 5 (allocation-free delivery): the defaults are now columnar
 ``BatchView`` delivery on the calendar-queue scheduler — the original
 pin grids run under those defaults, so PINNED passing at all *is* the
 bit-for-bit proof for the new hot path.  The additional sections pin
-the compat configurations against the same numbers: ``columnar=False``
-(per-row Record materialization, the pre-BatchView delivery pattern)
-and ``scheduler="heap"`` (the pre-calendar global heap) must reproduce
-the identical metrics, sink digests and sweep fingerprints in both
-delivery modes, with only ``record_objects_materialized`` allowed to
-differ between the columnar settings.
+the compat configuration ``columnar=False`` (per-row Record
+materialization, the pre-BatchView delivery pattern) against the same
+numbers, with only ``record_objects_materialized`` allowed to differ,
+and rerun the grid with the calendar queue on its bucketed wheel from
+the first push: its rows must equal the default (heap-phase) rows.
 
 PR 7 (telemetry): ``latency_mean``/``latency_p50``/``latency_p99`` were
 re-pinned (full precision, captured at the PR 6 head + histogram change
@@ -40,23 +39,19 @@ unchanged, which is the telemetry-off inertness proof; the explicit
 key-absence check below pins that no telemetry/profiler field appears
 at the defaults.
 
-PR 9 (fused fetch/delivery cohorts): the default is now
-``fetch_mode="fused"`` — one fused fetch cycle per poll and same-tick
+PR 9 (fetch/delivery cohorts): one fetch cycle per poll, and same-tick
 wakeups/deliveries coalesced into cohort events.  Coalescing merges
 events, so the two event-loop counters shrink in wakeup mode (each
-`_notify` wakes all waiters through one cohort event instead of one
-event per consumer); ``FUSED_EVENTS`` pins the fused counts.  Every
-other pinned field is bit-identical, and the ``legacy_rows`` section
-re-runs the grid at ``fetch_mode="legacy"`` asserting the original
-PINNED numbers exactly — the proof that the hot-path hoisting refactor
-(shared by both modes) changed nothing, isolating the event delta to
-cohort coalescing alone.  Poll mode registers no waiters and the grid
-has one partition per topic, so poll rows are event-identical too.
+``_notify`` wakes all waiters through one cohort event instead of one
+event per consumer); ``FUSED_EVENTS`` pins those counts, and every
+other PINNED field is unchanged.  ``ROW_DIGESTS`` pins every field of
+each row but the wall clock, recorded at commit 1e45a58.
 """
 import hashlib
 
 import pytest
 
+from _pins import metrics_digest, queue_phase
 from repro.core import Engine, PipelineSpec
 from repro.sweep import SweepSpec, run_sweep
 
@@ -136,8 +131,14 @@ FUSED_EVENTS = {
     (12, "wakeup"): {"engine_events": 1716, "events_scheduled": 1719},
 }
 
-# the counters allowed to differ between fused and legacy fetch modes
-EVENT_KEYS = ("engine_events", "events_scheduled", "events_cancelled")
+# every metric of each row but wall_s (sha256 prefix of canonical
+# JSON, tests/_pins.py), recorded at commit 1e45a58
+ROW_DIGESTS = {
+    (8, "poll"): "3228f0c87e77a502",
+    (8, "wakeup"): "e4fce724f3e05cba",
+    (12, "poll"): "b911b04834dc5639",
+    (12, "wakeup"): "0965ab3cfeeff5ca",
+}
 
 
 def _pins(key):
@@ -216,6 +217,7 @@ def test_columnar_path_materializes_no_records(rows):
 def _variant_rows(**base_over):
     grid = SweepSpec(name="ci_smoke_pin_variant", axes=dict(GRID.axes),
                      base={**GRID.base, **base_over})
+    # workers=1 runs inline, so a queue-phase patch reaches the engines
     res = run_sweep(grid, workers=1, cache_dir=None)
     return {(r["params"]["n_hosts"], r["params"]["delivery"]):
             r["metrics"] for r in res.rows}
@@ -227,13 +229,10 @@ def record_mode_rows():
 
 
 @pytest.fixture(scope="module")
-def heap_scheduler_rows():
-    return _variant_rows(scheduler="heap")
-
-
-@pytest.fixture(scope="module")
-def legacy_rows():
-    return _variant_rows(fetch_mode="legacy")
+def wheel_phase_rows():
+    with pytest.MonkeyPatch.context() as mp:
+        queue_phase(mp, "wheel")
+        return _variant_rows()
 
 
 @pytest.mark.parametrize("key", sorted(PINNED))
@@ -254,34 +253,23 @@ def test_record_mode_reproduces_pins_and_columnar_rows(
 
 @pytest.mark.parametrize("key", sorted(PINNED))
 def test_heap_scheduler_reproduces_calendar_rows(
-        rows, heap_scheduler_rows, key):
-    got = heap_scheduler_rows[key]
+        rows, wheel_phase_rows, key):
+    # the queue's wheel phase pops in the same (t, seq) order as its
+    # heap phase, so the whole row is unchanged
+    got = wheel_phase_rows[key]
     for field, want in _pins(key).items():
         assert got[field] == want, \
-            f"{key} (heap): metrics[{field!r}] = {got[field]!r}"
+            f"{key} (wheel): metrics[{field!r}] = {got[field]!r}"
     col = rows[key]
     assert {k: v for k, v in got.items() if k != "wall_s"} == \
         {k: v for k, v in col.items() if k != "wall_s"}
 
 
 @pytest.mark.parametrize("key", sorted(PINNED))
-def test_legacy_fetch_mode_reproduces_original_pins_exactly(
-        rows, legacy_rows, key):
-    # PR 9: legacy mode schedules per-consumer wakeups and per-partition
-    # deliver events exactly as before the fused-cohort refactor — it
-    # must hit the ORIGINAL pre-refactor PINNED numbers bit-for-bit,
-    # event counters included.  This isolates the hoisted `_fetch` body
-    # (shared by both modes) from cohort coalescing (fused-only).
-    got = legacy_rows[key]
-    for field, want in PINNED[key].items():
-        assert got[field] == want, \
-            f"{key} (legacy fetch): metrics[{field!r}] = {got[field]!r}, " \
-            f"pinned {want!r}"
-    # against the fused run: only the event-loop counters may differ
-    col = rows[key]
-    skip = set(EVENT_KEYS) | {"wall_s"}
-    assert {k: v for k, v in got.items() if k not in skip} == \
-        {k: v for k, v in col.items() if k not in skip}
+def test_legacy_fetch_mode_reproduces_original_pins_exactly(rows, key):
+    # every field of the row, not only PINNED's subset: event counters,
+    # per-partition tallies, histograms and the degradation counters
+    assert metrics_digest(rows[key]) == ROW_DIGESTS[key], key
 
 
 # ---------------------------------------------------------------------------

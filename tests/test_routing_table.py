@@ -4,7 +4,7 @@
 the legacy per-source networkx Dijkstra — not approximately: engine
 event streams, monitor event logs, per-message delivery times, every
 fingerprinted metric including the ``path_queries``/``reach_computes``
-counters, across both delivery modes, both schedulers, and under an
+counters, across both delivery modes, both queue phases, and under an
 active chaos plan whose flapping links / crashes force repeated epoch
 invalidation (plus gray-loss ramps exercising the ``loss_epoch`` seam
 and slow-host faults exercising the no-invalidation query-time extras).
@@ -22,6 +22,7 @@ import networkx as nx
 import pytest
 
 from _hyp import given, settings, strategies as st
+from _pins import PHASES, queue_phase
 
 from repro.core import Engine
 from repro.core.netem import LinkCfg, Network
@@ -247,7 +248,7 @@ def test_uncached_baseline_forces_ondemand():
 
 
 # ---------------------------------------------------------------------------
-# Engine-level bit-identity under chaos, across delivery modes/schedulers
+# Engine-level bit-identity under chaos, across delivery modes/queue phases
 # ---------------------------------------------------------------------------
 
 
@@ -259,9 +260,9 @@ CHAOS_PARAMS = {
 }
 
 
-def run_mode(route_mode: str, delivery: str, scheduler: str):
+def run_mode(route_mode: str, delivery: str):
     params = {**CHAOS_PARAMS, "delivery": delivery,
-              "scheduler": scheduler, "route_mode": route_mode}
+              "route_mode": route_mode}
     spec = build_scenario(params)
     eng = Engine(spec, seed=int(params["seed"]))
     mon = eng.run(until=float(params["horizon"]))
@@ -272,11 +273,13 @@ def run_mode(route_mode: str, delivery: str, scheduler: str):
     return m, mon.events, deliveries, eng.n_chaos_faults
 
 
-@pytest.mark.parametrize("scheduler", ["calendar", "heap"])
+@pytest.mark.parametrize("scheduler", PHASES)
 @pytest.mark.parametrize("delivery", ["wakeup", "poll"])
-def test_route_modes_bit_identical_under_chaos(delivery, scheduler):
-    table = run_mode("table", delivery, scheduler)
-    legacy = run_mode("ondemand", delivery, scheduler)
+def test_route_modes_bit_identical_under_chaos(delivery, scheduler,
+                                               monkeypatch):
+    queue_phase(monkeypatch, scheduler)
+    table = run_mode("table", delivery)
+    legacy = run_mode("ondemand", delivery)
     assert table[3] > 0, "chaos plan expanded to nothing — weak test"
     assert table[0] == legacy[0]      # every fingerprinted metric
     assert table[1] == legacy[1]      # the full monitor event log

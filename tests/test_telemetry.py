@@ -8,8 +8,9 @@ The telemetry contract (ROADMAP):
   the simulation except the sampler's own events.
 - **on**: every artifact (series rings, stage histograms, flight-event
   and profiler call counts, exported traces) is bit-identical for a
-  fixed (spec, seed) across processes, schedulers and the columnar
-  axis; produce-side spans additionally agree across delivery modes.
+  fixed (spec, seed) across processes, both phases of the event queue
+  and the columnar axis; produce-side spans additionally agree across
+  delivery modes.
 - **bounded**: histograms are fixed-bin, series are rings with exact
   running aggregates — memory is O(1) in run length.
 """
@@ -18,6 +19,7 @@ import json
 import numpy as np
 import pytest
 
+from _pins import queue_phase
 from repro.core import Engine
 from repro.core.telemetry import LatencyHistogram, N_BINS, Series
 from repro.obs.trace import chrome_trace, validate_chrome_trace
@@ -121,12 +123,12 @@ def test_invalid_telemetry_cfg_is_rejected():
 
 
 # ---------------------------------------------------------------------------
-# Determinism across processes / schedulers / columnar / delivery modes
+# Determinism across processes / queue phases / columnar / delivery modes
 # ---------------------------------------------------------------------------
 
 FP_GRID = SweepSpec(
     name="telemetry_fp",
-    axes={"scheduler": ["calendar", "heap"]},
+    axes={"columnar": [1, 0]},
     base=BASE)
 
 
@@ -140,12 +142,13 @@ def test_telemetry_fingerprint_stable_across_processes(tmp_path):
             assert k in r["metrics"], k
 
 
-def test_telemetry_identical_across_scheduler_and_columnar():
+def test_telemetry_identical_across_scheduler_and_columnar(monkeypatch):
     _, cal = run_one()
-    _, heap = run_one(scheduler="heap")
     _, rec = run_one(columnar=0)
+    queue_phase(monkeypatch, "wheel")
+    _, wheel = run_one()
     for k in TEL_KEYS + ("profile_counts",):
-        assert cal[k] == heap[k], k
+        assert cal[k] == wheel[k], k
         assert cal[k] == rec[k], k
     # and the full metric surface matches up to the allocation counter
     # (columnar) / wall clock, same as the PR 5 parity contract
