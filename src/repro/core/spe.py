@@ -153,7 +153,8 @@ class StageParts:
       query instance (trace, then compile or compile-cache load);
     - ``stage_dispatch``: every later call;
     - ``stage_wait``: ``block_until_ready``;
-    - ``stage_readback``: the results read back to Python.
+    - ``stage_readback``: one host copy of the stage's results, and
+      the Python values built from it.
 
     Where JAX is loaded each part also runs under a
     ``jax.profiler.TraceAnnotation`` of the same name, so that it sits
@@ -959,6 +960,11 @@ class TrafficMetricsQuery(Query):
         parts.begin("stage_wait")
         conns.block_until_ready()
         parts.begin("stage_readback")
+        # one host copy of both results: device_get starts every copy
+        # before it waits on any (an indexed read per element would
+        # dispatch a device slice and block on its copy, one by one)
+        import jax
+        conns, bw = jax.device_get((conns, bw))
         payload = {
             "connections": {s: float(conns[i])
                             for s, i in self._sid.items()},
